@@ -363,6 +363,9 @@ def run(num_graphs: int = NUM_GRAPHS, rounds: int = ROUNDS):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks.common import emit_bench_json
 
     out = sys.argv[1] if len(sys.argv) > 1 else "BENCH_ci.json"

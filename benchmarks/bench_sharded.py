@@ -1,7 +1,8 @@
 """Shard sweep: replicated vs sharded_cols vs sharded_2d on a CPU mesh.
 
-Forces 8 host devices (must run standalone — the flag only takes effect
-before jax initializes, so this suite is NOT part of benchmarks/run.py):
+A CPU-only sweep: it pins the CPU backend and forces 8 host devices (must
+run standalone — both only take effect before jax initializes, so this
+suite is NOT part of benchmarks/run.py):
 
     PYTHONPATH=src:. python benchmarks/bench_sharded.py
 
@@ -37,6 +38,7 @@ from __future__ import annotations
 
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import time  # noqa: E402
@@ -49,6 +51,7 @@ from benchmarks.common import bench_graphs, emit, fixture_step_budget  # noqa: E
 from repro.core import DeviceTopology, plan_execution  # noqa: E402
 from repro.distributed import distributed_tc_count  # noqa: E402
 from repro.distributed.tc import Sharded2DExecutor, ShardedColsExecutor  # noqa: E402
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 # The big bench graphs take minutes per shard count through shard_map on
 # CPU; the sweep's subject is scheduling behaviour, so mid-size graphs do.
@@ -195,5 +198,6 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run()

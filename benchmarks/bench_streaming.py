@@ -163,6 +163,9 @@ def print_rows(rows, failures) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks.common import emit_bench_json
 
     out = sys.argv[1] if len(sys.argv) > 1 else "BENCH_ci.json"

@@ -176,6 +176,9 @@ print('ROWS ' + json.dumps(rows))
 def _recovery_rows() -> list[dict]:
     """Recovery bench on 8 forced host devices via a fresh interpreter."""
     env = dict(os.environ)
+    # A CPU gate by design: the child pins the CPU backend so it never
+    # contends with this process (or anything else) for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.pathsep.join(
@@ -564,4 +567,7 @@ def run(out_path: str = "BENCH_ci.json") -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(run(*sys.argv[1:2]))

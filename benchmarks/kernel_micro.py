@@ -1,5 +1,11 @@
-"""Kernel microbenchmarks (interpret mode on CPU — correctness-path timing;
-TPU performance comes from the §Roofline model, not these numbers).
+"""Kernel microbenchmarks.
+
+    PYTHONPATH=src python -m benchmarks.kernel_micro          # any backend
+    PYTHONPATH=src python -m benchmarks.kernel_micro --chip   # TPU only
+
+Without ``--chip`` the rows run on whatever backend JAX finds; on the CPU
+they are interpret-mode or jnp-mirror timings of the correctness path, not
+device numbers.
 
 The execute-stage rows compare the fused gather–AND–popcount path against
 the legacy gather-then-kernel path at two levels:
@@ -11,11 +17,17 @@ the legacy gather-then-kernel path at two levels:
     device accumulator, one host sync) vs the old per-chunk ``int()``-sync
     loop with its ragged-tail retrace.
 
-``hbm=`` derived fields carry the modeled execute-stage HBM bytes (the
-quantity a real TCIM/TPU deployment is bound by; see tc_gather_popcount).
+``hbm=`` derived fields carry the modeled execute-stage HBM bytes (see
+``tc_gather_popcount.modeled_hbm_bytes``).
+
+``--chip`` times the execute kernel alone on a TPU: the Pallas gather
+kernel against the jnp mirror, in ns per pair, over com-youtube worklist
+pairs at published size and over as many uniformly random pairs.
 """
 from __future__ import annotations
 
+import argparse
+import sys
 import time
 
 import jax
@@ -27,6 +39,7 @@ from repro.core.executor import Executor
 from repro.core.sbf import SlicedBitmap
 from repro.kernels import ops, ref
 from repro.kernels.tc_gather_popcount import modeled_hbm_bytes
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, iters=3):
@@ -115,34 +128,6 @@ def run() -> None:
         f"fused_speedup={us_u / max(us_f, 1e-9):.2f}x",
     )
 
-    # Batched kernel: B pairs per grid step (in-kernel DMA loop) vs one pair
-    # per step. Interpret mode — correctness-path timing; on hardware the
-    # batched variant amortizes the per-step DMA overhead (see CAVEAT).
-    pb = 1 << 11
-    ridx_b = jnp.asarray(rng.integers(0, n_rows, pb, dtype=np.int32))
-    cidx_b = jnp.asarray(rng.integers(0, n_rows, pb, dtype=np.int32))
-    from repro.kernels.tc_gather_popcount import gather_total_pallas
-
-    base = int(gather_total_pallas(row_data, col_data, ridx_b, cidx_b, interpret=True))
-    for bp in (1, 8):
-        got_b = int(
-            gather_total_pallas(
-                row_data, col_data, ridx_b, cidx_b, interpret=True, block_pairs=bp
-            )
-        )
-        assert got_b == base, (bp, got_b, base)
-        us_b = _time(
-            lambda rd, cd, r, c, bp=bp: gather_total_pallas(
-                rd, cd, r, c, interpret=True, block_pairs=bp
-            ),
-            row_data, col_data, ridx_b, cidx_b,
-        )
-        emit(
-            f"execute/kernel_block_pairs{bp}_2kpairs",
-            us_b,
-            f"grid_steps={-(-pb // bp)};interpret=1",
-        )
-
     # Execute stage, multi-chunk: Executor pipeline vs per-chunk-sync loop,
     # with and without async double-buffering of the index uploads.
     pm = 200_000  # ragged: 3 full 64k chunks + a 3k tail
@@ -187,5 +172,78 @@ def run() -> None:
     emit("kernel/dense_mxu_tc_512", us, "")
 
 
+def run_chip(pairs: int = 1 << 20, iters: int = 3) -> None:
+    """The execute kernel alone on a TPU, against the jnp mirror.
+
+    ``gather_total_pallas`` and ``gather_total_reference`` each run the
+    first ``pairs`` com-youtube worklist pairs, then ``pairs`` uniformly
+    random pairs, against the graph's pow2-padded device stores. A row's
+    time is the best of ``iters`` runs after a warm-up; the two paths must
+    give the same total.
+    """
+    from repro.configs.tcim_graphs import GRAPHS
+    from repro.core import build_sbf, build_worklist
+    from repro.graphs import build_graph, rmat
+    from repro.kernels.tc_gather_popcount import (
+        gather_total_pallas,
+        gather_total_reference,
+    )
+
+    cfg = GRAPHS["com-youtube"]
+    t0 = time.perf_counter()
+    g = build_graph(rmat(cfg.n, cfg.m, seed=cfg.seed), reorder=True)
+    sb = build_sbf(g, 64)
+    wl = build_worklist(g, sb)
+    print(f"# com-youtube host build {time.perf_counter() - t0:.1f}s, "
+          f"{wl.num_pairs} pairs", flush=True)
+    ex = Executor(sb)  # device-resident, pow2-padded stores
+    rng = np.random.default_rng(0)
+    index_sets = {
+        "worklist": (wl.pair_row_pos[:pairs], wl.pair_col_pos[:pairs]),
+        "random": (
+            rng.integers(0, sb.row_slice_data.shape[0], pairs),
+            rng.integers(0, sb.col_slice_data.shape[0], pairs),
+        ),
+    }
+    for label, (r, c) in index_sets.items():
+        args = (
+            ex.row_data, ex.col_data,
+            jax.device_put(np.asarray(r, np.int32)),
+            jax.device_put(np.asarray(c, np.int32)),
+        )
+        totals = {}
+        for impl, fn in (
+            ("mirror", gather_total_reference), ("pallas", gather_total_pallas)
+        ):
+            compiled = jax.jit(fn).lower(*args).compile()
+            totals[impl] = int(compiled(*args))
+            best = min(
+                _time(compiled, *args, iters=1) for _ in range(iters)
+            )
+            emit(
+                f"chip/{impl}_{label}_{len(r)}pairs",
+                best,
+                f"ns_per_pair={best * 1e3 / len(r)};"
+                f"total={totals[impl]};device={jax.devices()[0].device_kind}",
+            )
+        assert totals["pallas"] == totals["mirror"], (label, totals)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description="kernel microbenchmarks")
+    ap.add_argument("--chip", action="store_true",
+                    help="time the execute kernel alone on a TPU")
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    if not args.chip:
+        run()
+        return 0
+    if jax.devices()[0].platform != "tpu":
+        print("kernel_micro --chip needs a TPU", file=sys.stderr)
+        return 1
+    run_chip()
+    return 0
+
+
 if __name__ == "__main__":
-    run()
+    sys.exit(main())

@@ -39,6 +39,7 @@ from repro.core.energymodel import PAPER_TABLE5, tcim_latency_energy
 from repro.core.executor import Executor
 from repro.core.tcim import tcim_count_graph
 from repro.kernels.tc_gather_popcount import modeled_hbm_bytes
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def run(names=None) -> list[dict]:
@@ -132,4 +133,5 @@ def run(names=None) -> list[dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

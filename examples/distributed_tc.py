@@ -13,25 +13,33 @@ Three placements of the same count (see core/plan.py):
     and column range j, and every pair executes on its owner block. The
     placement that lets row stores exceed one device's memory.
 
-Forces 8 host devices so the demo is genuinely multi-device on CPU (remove
-the flag on a real pod).
+The mesh is built from the devices present: every device on one axis for
+the replicated and sharded_cols placements, and the most nearly square
+(rows, cols) grid over them for sharded_2d. On a CPU, force several host
+devices to make the demo genuinely multi-device:
 
-    PYTHONPATH=src python examples/distributed_tc.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python examples/distributed_tc.py
 """
-import os
+import math
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+import jax
 
-import jax  # noqa: E402
-
-from repro.core import build_sbf, build_worklist, plan_execution, DeviceTopology  # noqa: E402
-from repro.distributed import (  # noqa: E402
+from repro.core import build_sbf, build_worklist, plan_execution, DeviceTopology
+from repro.distributed import (
     Sharded2DExecutor,
     ShardedColsExecutor,
     distributed_tc_count,
 )
-from repro.graphs import build_graph, rmat  # noqa: E402
-from repro.graphs.exact import triangles_intersection  # noqa: E402
+from repro.graphs import build_graph, rmat
+from repro.graphs.exact import triangles_intersection
+from repro.runtime.compile_cache import enable_compile_cache
+
+
+def grid_for(n_dev: int) -> tuple[int, int]:
+    """The most nearly square (rows, cols) grid with rows * cols == n_dev."""
+    rows = max(r for r in range(1, math.isqrt(n_dev) + 1) if n_dev % r == 0)
+    return n_dev // rows, rows
 
 
 def main():
@@ -39,8 +47,9 @@ def main():
     g = build_graph(edges, reorder=True)
     sbf = build_sbf(g)
     wl = build_worklist(g, sbf)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
     n_dev = len(jax.devices())
+    grid = grid_for(n_dev)
+    mesh = jax.make_mesh((n_dev,), ("data",))
     print(f"graph |V|={g.n} |E|={g.m}; work list: {wl.num_pairs} slice pairs")
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} ({n_dev} devices)")
 
@@ -65,12 +74,12 @@ def main():
     print(f"  stripes: min={min(stripe_pairs)} max={max(stripe_pairs)} "
           f"imbalance={plan.imbalance:.2f}")
 
-    # Both stores sharded over a 4x2 (row, col) owner grid, pair-count-
-    # weighted ranges: neither store is replicated any more.
-    mesh2 = jax.make_mesh((4, 2), ("r", "c"))
+    # Both stores sharded over a (row, col) owner grid, pair-count-weighted
+    # ranges: neither store is replicated any more.
+    mesh2 = jax.make_mesh(grid, ("r", "c"))
     plan2 = plan_execution(
         sbf, wl, DeviceTopology(num_devices=n_dev),
-        placement="sharded_2d", grid=(4, 2),
+        placement="sharded_2d", grid=grid,
     )
     ex2 = Sharded2DExecutor(sbf, mesh2, plan2)
     got_2d = ex2.count_plan(plan2)
@@ -90,12 +99,12 @@ def main():
     # a budget small enough that the count is genuinely multi-step.
     plan_even = plan_execution(
         sbf, wl, DeviceTopology(num_devices=n_dev),
-        placement="sharded_2d", grid=(4, 2), split="even",
+        placement="sharded_2d", grid=grid, split="even",
     )
     budget = 1 << 13
     fixed = plan_execution(
         sbf, wl, DeviceTopology(num_devices=n_dev),
-        placement="sharded_2d", grid=(4, 2), chunk_pairs=budget,
+        placement="sharded_2d", grid=grid, chunk_pairs=budget,
         row_bounds=plan_even.row_bounds, col_bounds=plan_even.col_bounds,
     )
     ex_fix = Sharded2DExecutor(sbf, mesh2, fixed, chunk_pairs=budget)
@@ -120,4 +129,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

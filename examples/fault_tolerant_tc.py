@@ -14,13 +14,14 @@ and finishes. Because the reduction is a commutative integer monoid over
 disjoint pair windows, the resumed count is bit-identical to an
 uninterrupted run — at most ``checkpoint_every`` steps are replayed.
 
-Forces 8 host devices so the demo is genuinely multi-device on CPU
-(remove the flag on a real pod).
+A CPU-only demo: it pins the CPU backend and forces 8 host devices, so it
+is genuinely multi-device and never takes an accelerator by accident.
 
     PYTHONPATH=src python examples/fault_tolerant_tc.py
 """
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import shutil  # noqa: E402
@@ -38,6 +39,7 @@ from repro.distributed import (  # noqa: E402
 )
 from repro.graphs import build_graph, rmat  # noqa: E402
 from repro.runtime import FailureInjector  # noqa: E402
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -98,4 +100,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import numpy as np
 from repro.core.metrics import clustering_coefficients, edge_support, max_truss
 from repro.graphs import build_graph, rmat
 from repro.graphs.exact import triangles_intersection
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -35,4 +36,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

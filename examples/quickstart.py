@@ -22,6 +22,7 @@ from repro.core import (
 from repro.core.energymodel import tcim_latency_energy
 from repro.graphs import build_graph, rmat
 from repro.graphs.exact import triangles_intersection
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -29,6 +29,7 @@ from repro.core import Executor, build_sbf, build_worklist
 from repro.core.executor import ExecutorPool
 from repro.graphs import build_graph, rmat
 from repro.launch.tc_serve import ServeConfig, TCServer
+from repro.runtime.compile_cache import enable_compile_cache
 
 NUM_GRAPHS = 32
 ROUNDS = 3
@@ -134,4 +135,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

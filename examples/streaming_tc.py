@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.core import StreamingTCState, tcim_count, tcim_count_delta
 from repro.graphs import build_graph, erdos_renyi
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -67,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

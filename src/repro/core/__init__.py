@@ -51,6 +51,7 @@ from repro.core.sbf import (
     update_sbf,
 )
 from repro.core.build import (
+    DeviceCapacityError,
     DeviceBuild,
     DeviceBuildFuture,
     DeviceWorklist,
@@ -123,6 +124,7 @@ __all__ = [
     "remaining_worklist",
     "replan_fixed",
     "weighted_range_bounds",
+    "DeviceCapacityError",
     "DeviceBuild",
     "DeviceBuildFuture",
     "DeviceWorklist",

@@ -55,6 +55,7 @@ from repro.graphs.csr import (
 )
 
 __all__ = [
+    "DeviceCapacityError",
     "DeviceBuild",
     "DeviceBuildFuture",
     "DeviceWorklist",
@@ -68,6 +69,13 @@ __all__ = [
 ]
 
 _INT32_LIMIT = 2**31 - 1
+
+
+class DeviceCapacityError(ValueError):
+    """The device build refused a graph (or delta batch) whose candidate
+    total reaches int32 device indexing. The only device-build failure that
+    ``build='auto'`` answers by building on the host instead; compile and
+    runtime errors of the device path are never caught that way."""
 
 # kind -> jitted fn, built lazily (mirrors graphs.csr._DEVICE_JITS).
 _JITS: dict = {}
@@ -315,7 +323,7 @@ def _make_worklist(
     """Dispatch the expansion/search/compaction; trim pairs to their bucket."""
     jits = _get_jits()
     if cand_shadow >= _CAND_GUARD:
-        raise ValueError(
+        raise DeviceCapacityError(
             f"candidate total ~{cand_shadow:.3g} is at or past int32 device "
             "indexing; build this graph on the host (build='host')"
         )
@@ -524,7 +532,7 @@ def device_delta_worklist(
     cand, shadow = jits["cand_total"](src_d, m, row_ptr)
     cand_shadow = float(np.asarray(shadow).reshape(1).view(np.float32)[0])
     if cand_shadow >= _CAND_GUARD:
-        raise ValueError(
+        raise DeviceCapacityError(
             f"delta candidate total ~{cand_shadow:.3g} is at or past int32 "
             "device indexing; split the batch or build on the host"
         )

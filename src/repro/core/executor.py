@@ -298,13 +298,18 @@ def scatter_update_trace_count() -> int:
         return -1
 
 
+def _execute_impl(mode: str, use_kernel: bool | None) -> str:
+    if mode != "fused":
+        return mode
+    return "pallas" if ops.gather_kernel_selected(use_kernel) else "jnp_mirror"
+
+
 @functools.lru_cache(maxsize=None)
 def _chunk_step_fn(
     mode: str,
     interpret: bool | None,
     use_kernel: bool | None,
     donate: str,
-    block_pairs: int | None = None,
 ):
     """Module-level jitted chunk step, shared by every Executor with the same
     config — one-shot API calls (tcim_count per graph) amortize traces and
@@ -323,7 +328,6 @@ def _chunk_step_fn(
             return ops.popcount_and_gather_total(
                 row_data, col_data, ridx, cidx,
                 use_kernel=use_kernel, interpret=interpret,
-                block_pairs=block_pairs,
             )
         mask = (ridx >= 0) & (cidx >= 0)
         rows = jnp.take(row_data, jnp.maximum(ridx, 0), axis=0)
@@ -361,13 +365,15 @@ class Executor:
         chunk_pairs: int = 1 << 20,
         interpret: bool | None = None,
         use_kernel: bool | None = None,
-        block_pairs: int | None = None,
         double_buffer: bool = True,
         pad_stores_pow2: bool = True,
     ):
         if mode not in EXECUTOR_MODES:
             raise ValueError(f"mode {mode!r} not in {EXECUTOR_MODES}")
         self.mode = mode
+        # What runs each chunk: in fused mode the Pallas kernel or the jnp
+        # mirror (ops.gather_kernel_selected decides), else the named mode.
+        self.execute_impl = _execute_impl(mode, use_kernel)
         self.words_per_slice = int(sb.row_slice_data.shape[1])
         self.slice_bits = int(sb.slice_bits)
         self.double_buffer = double_buffer
@@ -388,12 +394,10 @@ class Executor:
         self._chunk_jit = _chunk_step_fn(
             mode, interpret, use_kernel,
             donate="none" if on_cpu() else "all",
-            block_pairs=block_pairs,
         )
         self._chunk_jit_resident = _chunk_step_fn(
             mode, interpret, use_kernel,
             donate="none" if on_cpu() else "acc",
-            block_pairs=block_pairs,
         )
         # Weakrefs to unresolved CountFutures. While any is alive the
         # executor's device stores back in-flight dispatches, so pools must
@@ -798,6 +802,9 @@ class ExecutorPool:
             "misses": self.misses,
             "trace_groups": len(groups),
             "max_group": max(groups.values(), default=0),
+            "execute_impls": sorted(
+                {ex.execute_impl for _, ex in self._entries.values()}
+            ),
         }
 
 
@@ -922,6 +929,7 @@ class MultiGraphExecutor:
         self.max_fused_pairs = int(max_fused_pairs)
         self._interpret = interpret
         self._use_kernel = use_kernel
+        self.execute_impl = _execute_impl("fused", use_kernel)
         self._batches: collections.OrderedDict[tuple, _FusedBatch] = (
             collections.OrderedDict()
         )
@@ -1022,4 +1030,5 @@ class MultiGraphExecutor:
             "hits": self.hits,
             "misses": self.misses,
             "buckets": sorted(self._steps),
+            "execute_impl": self.execute_impl,
         }

@@ -288,7 +288,7 @@ class StreamingTCState:
         if self._use_device_build and len(src):
             try:
                 return build_mod.device_delta_worklist(src, dst, sb)
-            except ValueError:
+            except build_mod.DeviceCapacityError:
                 if self._build == "device":
                     raise
                 # auto: int32 capacity exceeded — fall back to the host.

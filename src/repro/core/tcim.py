@@ -162,12 +162,14 @@ def _resolve_build(build: str, backend: str, mesh, m: int) -> str:
 
 def _try_device_build(make_build, build: str):
     """Run a device build; under ``build='auto'`` fall back to the host
-    front end when the device path raises one of its documented capability
-    errors (int32 index space) instead of crashing a request that never
-    pinned the build. An explicit ``build='device'`` still raises."""
+    front end only when the device build refuses the graph's size
+    (``DeviceCapacityError``: int32 index space) instead of crashing a
+    request that never pinned the build. Every other error — a kernel the
+    compiler refuses, a device fault — surfaces, and an explicit
+    ``build='device'`` raises the capacity error too."""
     try:
         return make_build()
-    except ValueError:
+    except build_mod.DeviceCapacityError:
         if build != "auto":
             raise
         return None
@@ -182,14 +184,16 @@ def _execute_worklist_async(
     mesh,
     pool: ExecutorPool | None,
     schedule: str,
-) -> tuple[CountFuture, str, float]:
+) -> tuple[CountFuture, str, float, str]:
     """Plan and dispatch the execute stage; defer the host readback.
 
     Resolves ``placement`` against the device topology (the mesh's, when
     given), then dispatches on a pooled replicated Executor, the
     column-sharded distributed path, or the 2-D owner-grid path — every
     branch returns with its steps enqueued and the close deferred to the
-    future. Returns (future, resolved placement, planning seconds).
+    future. Returns (future, resolved placement, planning seconds, execute
+    implementation). Every mesh path runs the fused jnp mirror inside
+    shard_map.
     """
     grid = None
     if mesh is not None:
@@ -224,7 +228,7 @@ def _execute_worklist_async(
         )
         # count(wl, plan) falls back to the pooled executor's resident
         # bounds when the fresh plan's ranges differ — no store re-upload.
-        return ex.count_async(wl, plan), plan.placement, plan_s
+        return ex.count_async(wl, plan), plan.placement, plan_s, ex.execute_impl
     if plan.placement == "sharded_cols":
         if mesh is None:
             raise ValueError(
@@ -236,12 +240,17 @@ def _execute_worklist_async(
         ex = pooled_sharded_executor(
             sb, mesh, chunk_pairs=chunk_pairs, schedule=schedule
         )
-        return ex.count_plan_async(plan), plan.placement, plan_s
+        return (
+            ex.count_plan_async(plan), plan.placement, plan_s, ex.execute_impl
+        )
     if mesh is not None and topo.num_devices > 1:
         # Replicated over a real mesh: stores on every device, work-list
         # stripes dealt across it, scalar psum close. Runs the fused jnp
         # mirror inside shard_map, so `backend` does not apply here.
-        from repro.distributed.tc import distributed_tc_count_async
+        from repro.distributed.tc import (
+            MESH_EXECUTE_IMPL,
+            distributed_tc_count_async,
+        )
 
         return (
             distributed_tc_count_async(
@@ -249,12 +258,13 @@ def _execute_worklist_async(
             ),
             plan.placement,
             plan_s,
+            MESH_EXECUTE_IMPL,
         )
     # NOT `pool or ...`: an empty ExecutorPool is falsy (it has __len__).
     ex = (pool if pool is not None else _DEFAULT_POOL).get(
         sb, mode=_EXECUTOR_MODE[backend], chunk_pairs=chunk_pairs
     )
-    return ex.count_async(wl), plan.placement, plan_s
+    return ex.count_async(wl), plan.placement, plan_s, ex.execute_impl
 
 
 def _execute_bitgemm(g: Graph, chunk_rows: int = 2048) -> int:
@@ -309,6 +319,7 @@ def _finish_host(
                 f"{placement!r}"
             )
         from repro.distributed.resilient import resilient_tc_count
+        from repro.distributed.tc import MESH_EXECUTE_IMPL
 
         t0 = time.perf_counter()
         triangles, rinfo = resilient_tc_count(
@@ -325,6 +336,7 @@ def _finish_host(
         )
         stats["placement"] = "sharded_2d"
         stats["build"] = build_label
+        stats["execute_impl"] = MESH_EXECUTE_IMPL
         stats["recovery"] = rinfo
         res = TCResult(triangles, backend, stats, timings)
         if async_:
@@ -333,7 +345,7 @@ def _finish_host(
             return fut
         return res
     t0 = time.perf_counter()
-    fut, resolved, plan_s = _execute_worklist_async(
+    fut, resolved, plan_s, impl = _execute_worklist_async(
         sb, wl, backend, chunk_pairs, placement, mesh, pool, schedule
     )
     dispatch_s = time.perf_counter() - t0 - plan_s
@@ -341,6 +353,7 @@ def _finish_host(
     stats = sbf_mod.sbf_stats(g, sb, wl) if collect_stats else {"n": g.n, "m": g.m}
     stats["placement"] = resolved
     stats["build"] = build_label
+    stats["execute_impl"] = impl
     if async_:
         timings["execute"] = dispatch_s
         return TCFuture(fut, backend, stats, timings)
@@ -386,6 +399,7 @@ def _finish_device(
         )
         stats["placement"] = "replicated"
         stats["build"] = "device"
+        stats["execute_impl"] = ex.execute_impl
         if async_:
             timings["execute"] = dispatch_s
             return TCFuture(fut, backend, stats, timings)

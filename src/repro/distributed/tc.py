@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.executor import CountFuture, staged_uploads
 from repro.core.plan import (
@@ -84,6 +84,27 @@ __all__ = [
 ]
 
 TC_PLACEMENTS = ("replicated", "sharded_cols", "sharded_2d")
+
+# What every mesh step runs per device inside shard_map: the fused jnp
+# mirror (``gather_total_reference``), whatever the single-device backend.
+MESH_EXECUTE_IMPL = "jnp_mirror"
+
+
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``: same devices, same names.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, which make a store's
+    sharding part of its type; the streaming store scatter
+    (``core.executor.apply_store_lanes``) then cannot infer its gather's
+    output sharding. The sharded executors place every array themselves
+    through ``NamedSharding``, so they run on the Auto view of the mesh.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(AxisType.Auto,) * mesh.devices.ndim,
+    )
 
 
 def shard_worklist(wl: Worklist, num_shards: int) -> tuple[np.ndarray, np.ndarray]:
@@ -207,6 +228,8 @@ class _StripeScheduleDriver:
       the final host readback happens at ``result()``, so fleet callers
       overlap graph i's close with graph i+1's stripe assembly.
     """
+
+    execute_impl = MESH_EXECUTE_IMPL
 
     def _validate_int32_floor(self, noun: str, remedy: str) -> None:
         """Constructor guard: the packed scheduler's width-1 progress floor
@@ -465,6 +488,7 @@ class ShardedColsExecutor(_StripeScheduleDriver):
         if schedule not in SCHEDULES:
             raise ValueError(f"schedule {schedule!r} not in {SCHEDULES}")
         self.schedule = schedule
+        mesh = _auto_axes(mesh)
         self.mesh = mesh
         self.axis_names = tuple(mesh.axis_names)
         self.num_shards = int(np.prod(mesh.devices.shape))
@@ -616,6 +640,7 @@ class Sharded2DExecutor(_StripeScheduleDriver):
                 f"sharded_2d needs a 2-axis mesh, got {mesh.devices.ndim} "
                 f"axes {tuple(mesh.axis_names)}"
             )
+        mesh = _auto_axes(mesh)
         self.mesh = mesh
         self.axis_names = tuple(mesh.axis_names)
         self.grid = tuple(int(x) for x in mesh.devices.shape)

@@ -34,5 +34,6 @@ def on_cpu() -> bool:
 
 
 def on_tpu() -> bool:
-    """True on real TPUs — gates pltpu-specific features (scalar prefetch)."""
+    """True on real TPUs — where the gather primitives run the Pallas kernel
+    (``ops.gather_kernel_selected``)."""
     return jax.default_backend() == "tpu"

@@ -2,7 +2,7 @@
 
 Handle padding/layout so callers never think about block alignment, and pick
 ``interpret=True`` automatically on the CPU backend (the validation mode for
-this container; on real TPUs the same calls compile to Mosaic).
+tests; on a TPU the same calls compile to Mosaic).
 """
 from __future__ import annotations
 
@@ -29,6 +29,7 @@ __all__ = [
     "bitgemm",
     "dense_mxu_tc",
     "INT32_SAFE_WORDS",
+    "gather_kernel_selected",
 ]
 
 # Largest number of uint32 words whose AND-popcount total provably fits the
@@ -38,6 +39,17 @@ INT32_SAFE_WORDS = (2**31 - 1) // 32
 
 def _interpret(flag: bool | None) -> bool:
     return on_cpu() if flag is None else flag
+
+
+def gather_kernel_selected(use_kernel: bool | None = None) -> bool:
+    """Whether the gather primitives below run the Pallas kernel.
+
+    ``use_kernel=None`` means: on a TPU, always the kernel — the jnp mirror
+    never stands in for it there; elsewhere the mirror (on the CPU the
+    interpreted kernel is a correctness tool, and on a GPU XLA fuses the
+    mirror). The one place this choice is made, so callers can report it.
+    """
+    return on_tpu() if use_kernel is None else bool(use_kernel)
 
 
 def _pad_rows(a: jax.Array, multiple: int) -> jax.Array:
@@ -122,7 +134,6 @@ def popcount_and_gather_total(
     *,
     use_kernel: bool | None = None,
     interpret: bool | None = None,
-    block_pairs: int | None = None,
 ) -> jax.Array:
     """Fused gather–AND–popcount total over a work-list chunk -> int32 scalar.
 
@@ -131,15 +142,9 @@ def popcount_and_gather_total(
     computation — no ``[P, W]`` gathered operands ever materialize in HBM.
     Negative indices are exact no-ops (the chunk-padding/sharding sentinel).
 
-    ``use_kernel=None`` picks the scalar-prefetch Pallas kernel on TPU only
-    (``PrefetchScalarGridSpec`` is a pltpu feature) and the vectorized jnp
-    mirror elsewhere — on CPU the per-pair interpreter grid is a correctness
-    tool rather than a performance path, and on GPU XLA fuses the mirror
-    (both paths share semantics and are cross-checked in tests).
-
-    ``block_pairs`` (kernel path only) batches B pairs per grid step with an
-    in-kernel DMA loop, amortizing per-step overhead; ``None``/1 keeps the
-    one-pair-per-step index-mapped pipeline.
+    ``use_kernel`` picks the Pallas kernel or the vectorized jnp mirror
+    (``gather_kernel_selected``; both share semantics and are cross-checked
+    in tests).
     """
     assert row_idx.shape == col_idx.shape, (row_idx.shape, col_idx.shape)
     p = row_idx.shape[0]
@@ -152,16 +157,13 @@ def popcount_and_gather_total(
             f"accumulator (max safe words: {INT32_SAFE_WORDS}); "
             "reduce chunk_pairs"
         )
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    if use_kernel:
+    if gather_kernel_selected(use_kernel):
         return gather_total_pallas(
             row_data,
             col_data,
             row_idx.astype(jnp.int32),
             col_idx.astype(jnp.int32),
             interpret=_interpret(interpret),
-            block_pairs=1 if block_pairs is None else block_pairs,
         )
     return gather_total_reference(row_data, col_data, row_idx, col_idx)
 
@@ -202,9 +204,7 @@ def popcount_and_gather_segment_totals(
             f"the int32 accumulator (max safe words: {INT32_SAFE_WORDS}); "
             "route the graph solo with a smaller chunk_pairs"
         )
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    if use_kernel:
+    if gather_kernel_selected(use_kernel):
         return gather_segment_totals_pallas(
             row_data,
             col_data,

@@ -9,36 +9,39 @@ gathered word crossed HBM twice, plus a full materialized intermediate.
 This module is the device analogue of the MRAM computational array: the
 *indices* travel to the kernel, not the operands.
 
-  * ``gather_total_pallas`` — scalar-prefetch Pallas kernel. The pair index
-    arrays are ``num_scalar_prefetch`` operands of a
-    ``pltpu.PrefetchScalarGridSpec``; they land in SMEM before the grid runs.
-    With ``block_pairs=1`` they drive the index maps of ``(1, W)`` BlockSpecs
-    over the slice stores, so Mosaic's pipeline DMAs exactly the valid slice
-    words straight from the HBM-resident stores into VMEM — one pass, no
-    gathered intermediate. Consecutive identical indices reuse the
-    already-resident block (free temporal locality for hot rows, the same
-    effect as TCIM's reuse-aware cache). Negative indices are masked no-ops,
+  * ``gather_segment_totals_pallas`` — the Pallas kernel. On a TPU, XLA
+    lays a ``[R, W]`` uint32 slice store out as W word planes in
+    ``(W, 128)`` tiles (layout ``{0,1:T(W,128)}``), so the kernel views the
+    store as ``[R/128, W, 128]`` — a bitcast, no copy — and its DMA unit is
+    one whole tile: the 128 slices that share slice ``r``'s tile. The
+    stores stay in HBM (``memory_space=ANY``). Each grid step takes a
+    1024-pair block of both index arrays into SMEM (1024 matches XLA's
+    ``T(1024)`` layout of a 1-D int32 array), then walks it in batches of
+    ``BLOCK_PAIRS``: it starts one tile DMA per operand of every valid pair
+    of the batch, waits for all of them, and for each pair rotates the row
+    tile so slice ``r``'s lane lines up with slice ``c``'s, ANDs, popcounts,
+    and keeps only that lane. Negative indices are masked no-ops (no DMA),
     which is how the executor and the distributed engine pad ragged chunks.
+    The ``[G]`` per-segment totals live in SMEM; a batch never straddles a
+    segment, because both ``bucket`` and the batch size are powers of two.
+    The index arrays stream through SMEM block by block, so a call's pair
+    count is bounded only by the int32 accumulator, not by SMEM.
 
-    With ``block_pairs=B > 1`` each grid step instead issues an in-kernel
-    DMA loop: the stores stay in HBM (``memory_space=ANY``) and the body
-    starts ``2B`` async copies — one ``(1, W)`` row per prefetched index —
-    into ``(B, W)`` VMEM scratch, waits once, and reduces the whole block
-    with one vectorized AND+popcount. This amortizes per-grid-step overhead
-    over B pairs (a (1, W) block is 8–32 bytes, far below the native
-    (8, 128) tile, so step overhead dominates at B=1 on real hardware).
-
-    CAVEAT (untested on hardware): both variants have only been measured in
-    interpret mode in this container; validate on a real TPU and tune B
-    before trusting the kernel path in production.
-  * ``gather_total_reference`` — vectorized jnp mirror with identical
-    semantics (including the negative-index contract). On the CPU backend
-    (this container) the per-pair interpreter grid is a correctness tool,
-    not a performance path, so the executor runs this mirror instead; XLA
-    fuses gather+AND+popcount+reduce into one loop, which is the same
-    "no materialized operands" property at the XLA level. It deliberately
-    uses the kernels' SWAR popcount so the ``lax.population_count`` oracle
-    in ``kernels/ref.py`` stays an independent check.
+    Established on a TPU v5e: Mosaic accepts this kernel at real sizes
+    (``tests/test_tpu_compile.py`` compiles it for a described ``v5e:2x2``
+    at 2^21-row stores), and ``chip_smoke.py`` matches the exact oracle
+    through it on the chip. It takes about 175 ns per pair whether 32, 128
+    or 512 pairs' DMAs are in flight, 10x the jnp mirror's time
+    (``python -m benchmarks.kernel_micro --chip``), so the batch size is
+    fixed: the cost is the per-pair scalar loop, not the DMA depth.
+  * ``gather_total_pallas`` — the same kernel with one segment.
+  * ``gather_total_reference`` / ``gather_segment_totals_reference`` —
+    vectorized jnp mirrors with identical semantics (including the
+    negative-index contract). On the CPU backend the interpreted kernel is a
+    correctness tool, not a performance path, so the executor runs the
+    mirror there; XLA fuses gather+AND+popcount+reduce into one loop. The
+    mirrors use the kernels' SWAR popcount so the ``lax.population_count``
+    oracle in ``kernels/ref.py`` stays an independent check.
 
 Accumulation is int32; callers bound ``num_pairs * words_per_slice * 32``
 against the int32 limit (see ``kernels/ops.py`` and ``core/executor.py``).
@@ -62,86 +65,151 @@ __all__ = [
     "modeled_hbm_bytes",
 ]
 
-
-def _gather_total_kernel(ridx_ref, cidx_ref, row_ref, col_ref, out_ref):
-    """One pair per grid step: AND + SWAR popcount of an index-mapped block.
-
-    ``ridx_ref``/``cidx_ref`` are the SMEM scalar-prefetch refs — also
-    readable in the body, which is how padded (negative-index) pairs are
-    turned into exact no-ops without a separate mask operand.
-    """
-    p = pl.program_id(0)
-    valid = (ridx_ref[p] >= 0) & (cidx_ref[p] >= 0)
-    x = row_ref[...] & col_ref[...]
-    partial = jnp.where(valid, swar_popcount_u32(x).sum(), 0)
-
-    @pl.when(p == 0)
-    def _init():
-        out_ref[0, 0] = partial
-
-    @pl.when(p != 0)
-    def _acc():
-        out_ref[0, 0] += partial
+# Lane width of a store tile: the DMA unit is one (W, 128) tile.
+LANES = 128
+# Pairs per grid step. 1-D int32 arrays are laid out in T(1024) tiles, and
+# Mosaic refuses an SMEM index block that does not match that layout.
+INDEX_BLOCK = 1024
+# Pairs whose tile DMAs are in flight together (a power of two <= INDEX_BLOCK).
+BLOCK_PAIRS = 128
 
 
-def _gather_total_batched_kernel(
-    ridx_ref, cidx_ref, row_hbm, col_hbm, out_ref, row_buf, col_buf, sems,
-    *, block_pairs: int
+def _tile_view(store: jax.Array) -> jax.Array:
+    """``[R, W]`` store -> ``[ceil(R/128), W, 128]`` tiles (slice r is lane
+    ``r % 128`` of tile ``r // 128``). A bitcast of XLA's TPU layout when R
+    is a multiple of 128 (the executors' pow2 stores from 128 rows up);
+    smaller stores are zero-padded, which is exact (zero slices add 0)."""
+    rows, w = store.shape
+    padded = -(-rows // LANES) * LANES
+    if padded != rows:
+        store = jnp.pad(store, ((0, padded - rows), (0, 0)))
+    return store.reshape(padded // LANES, LANES, w).transpose(0, 2, 1)
+
+
+def _gather_segment_kernel(
+    ridx_ref, cidx_ref, row_tiles, col_tiles, out_ref, row_buf, col_buf, sems,
+    *, block_pairs: int, bucket: int,
 ):
-    """B pairs per grid step: an in-kernel DMA loop over prefetched indices.
-
-    The slice stores never leave HBM (``memory_space=ANY``); the body starts
-    one async copy per operand row into ``(B, W)`` VMEM scratch — all 2B
-    copies in flight before the first wait — then reduces the block with a
-    single vectorized AND+popcount. Out-of-range steps (the grid's ragged
-    tail) and negative (padding) indices are masked to zero; their DMAs are
-    still issued with clamped indices so every semaphore signals exactly
-    once.
-    """
+    """One 1024-pair index block: tile DMAs in batches, per-segment sums."""
     step = pl.program_id(0)
-    num_pairs = ridx_ref.shape[0]
-    base = step * block_pairs
-
-    def pair_copies(b):
-        i = jnp.minimum(base + b, num_pairs - 1)
-        r = jnp.maximum(ridx_ref[i], 0)
-        c = jnp.maximum(cidx_ref[i], 0)
-        return (
-            pltpu.make_async_copy(
-                row_hbm.at[pl.ds(r, 1)], row_buf.at[pl.ds(b, 1)], sems.at[0, b]
-            ),
-            pltpu.make_async_copy(
-                col_hbm.at[pl.ds(c, 1)], col_buf.at[pl.ds(b, 1)], sems.at[1, b]
-            ),
-        )
-
-    for b in range(block_pairs):  # start all 2B DMAs back-to-back
-        for dma in pair_copies(b):
-            dma.start()
-    for b in range(block_pairs):
-        for dma in pair_copies(b):
-            dma.wait()
-    valid = jnp.stack(
-        [
-            (base + b < num_pairs)
-            & (ridx_ref[jnp.minimum(base + b, num_pairs - 1)] >= 0)
-            & (cidx_ref[jnp.minimum(base + b, num_pairs - 1)] >= 0)
-            for b in range(block_pairs)
-        ]
-    )
-    pc = swar_popcount_u32(row_buf[...] & col_buf[...])  # (B, W) int32
-    partial = jnp.where(valid[:, None], pc, 0).sum()
+    w = row_buf.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (w, LANES), 1)
 
     @pl.when(step == 0)
-    def _init():
-        out_ref[0, 0] = partial
+    def _zero():
+        def zero(g, carry):
+            out_ref[g] = 0
+            return carry
 
-    @pl.when(step != 0)
-    def _acc():
-        out_ref[0, 0] += partial
+        jax.lax.fori_loop(0, out_ref.shape[0], zero, 0)
+
+    def copies(b, r, c):
+        return (
+            pltpu.make_async_copy(row_tiles.at[r // LANES], row_buf.at[b], sems.at[0]),
+            pltpu.make_async_copy(col_tiles.at[c // LANES], col_buf.at[b], sems.at[1]),
+        )
+
+    def batch(j, carry):
+        base = j * block_pairs
+
+        def pair(b):
+            r = ridx_ref[base + b]
+            c = cidx_ref[base + b]
+            return r, c, (r >= 0) & (c >= 0)
+
+        def start(b, carry):
+            r, c, valid = pair(b)
+
+            @pl.when(valid)
+            def _():
+                for dma in copies(b, r, c):
+                    dma.start()
+
+            return carry
+
+        def wait(b, carry):
+            # Every copy of a side shares one semaphore and one size, so all
+            # of them are waited for before any buffer is read.
+            r, c, valid = pair(b)
+
+            @pl.when(valid)
+            def _():
+                for dma in copies(b, r, c):
+                    dma.wait()
+
+            return carry
+
+        def accumulate(b, acc):
+            r, c, valid = pair(b)
+            lc = c % LANES
+            # Bring slice r's lane to slice c's lane, then AND the W words.
+            row = pltpu.roll(row_buf[b], (lc - r % LANES) % LANES, 1)
+            pc = swar_popcount_u32(row & col_buf[b])
+            return acc + jnp.where(valid & (lane == lc), pc, 0)
+
+        jax.lax.fori_loop(0, block_pairs, start, 0)
+        jax.lax.fori_loop(0, block_pairs, wait, 0)
+        acc = jax.lax.fori_loop(
+            0, block_pairs, accumulate, jnp.zeros((w, LANES), jnp.int32)
+        )
+        seg = (step * INDEX_BLOCK + base) // bucket
+        out_ref[seg] += jnp.sum(acc)
+        return carry
+
+    jax.lax.fori_loop(0, INDEX_BLOCK // block_pairs, batch, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_pairs"))
+def _segment_totals(
+    row_data, col_data, row_idx, col_idx, bucket, interpret,
+    block_pairs=BLOCK_PAIRS,
+):
+    """Run the kernel over ``row_idx``/``col_idx`` in ``bucket``-wide
+    segments; returns every segment of the 1024-padded index arrays.
+    ``block_pairs`` is left open only so tests can cover batches that do
+    not divide the pair count."""
+    if block_pairs < 1 or block_pairs & (block_pairs - 1) or block_pairs > INDEX_BLOCK:
+        raise ValueError(
+            f"block_pairs must be a power of two <= {INDEX_BLOCK}, got {block_pairs}"
+        )
+    p = row_idx.shape[0]
+    padded = -(-p // INDEX_BLOCK) * INDEX_BLOCK
+    if padded != p:
+        row_idx = jnp.pad(row_idx, (0, padded - p), constant_values=-1)
+        col_idx = jnp.pad(col_idx, (0, padded - p), constant_values=-1)
+    w = row_data.shape[1]
+    block_pairs = min(block_pairs, bucket)
+    grid_spec = pl.GridSpec(
+        grid=(padded // INDEX_BLOCK,),
+        in_specs=[
+            pl.BlockSpec((INDEX_BLOCK,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((INDEX_BLOCK,), lambda i: (i,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        scratch_shapes=[
+            pltpu.VMEM((block_pairs, w, LANES), jnp.uint32),
+            pltpu.VMEM((block_pairs, w, LANES), jnp.uint32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _gather_segment_kernel, block_pairs=block_pairs, bucket=bucket
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((-(-padded // bucket),), jnp.int32),
+        interpret=interpret,
+    )(row_idx, col_idx, _tile_view(row_data), _tile_view(col_data))
+
+
+def _check_operands(row_data, col_data, row_idx, col_idx) -> None:
+    assert row_idx.shape == col_idx.shape, (row_idx.shape, col_idx.shape)
+    assert row_data.ndim == col_data.ndim == 2
+    assert col_data.shape[1] == row_data.shape[1], (row_data.shape, col_data.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_total_pallas(
     row_data: jax.Array,  # [R, W] uint32 — row-side slice store (stays put)
     col_data: jax.Array,  # [C, W] uint32 — col-side slice store (stays put)
@@ -149,90 +217,22 @@ def gather_total_pallas(
     col_idx: jax.Array,  # [P] int32 work-list col positions (< 0 = no-op)
     *,
     interpret: bool = False,
-    block_pairs: int = 1,
 ) -> jax.Array:
     """Fused total popcount(row_data[row_idx] & col_data[col_idx]) -> int32.
 
-    The gather happens *inside* the kernel: scalar-prefetched indices drive
-    either the BlockSpec index maps (``block_pairs=1``) or an in-kernel DMA
-    loop over B-pair blocks (``block_pairs>1``), so each grid step's DMAs
-    pull valid slice pairs directly from the stores. Negative index pairs
-    contribute zero.
+    The gather happens *inside* the kernel: the indices drive tile DMAs
+    straight from the HBM-resident stores, ``BLOCK_PAIRS`` pairs in flight
+    at a time. Negative index pairs contribute zero.
     """
+    _check_operands(row_data, col_data, row_idx, col_idx)
     p = row_idx.shape[0]
-    assert row_idx.shape == col_idx.shape, (row_idx.shape, col_idx.shape)
-    assert row_data.ndim == col_data.ndim == 2
-    w = row_data.shape[1]
-    assert col_data.shape[1] == w, (row_data.shape, col_data.shape)
-    if block_pairs < 1:
-        raise ValueError(f"block_pairs must be >= 1, got {block_pairs}")
     if p == 0:
         return jnp.int32(0)
-    if block_pairs > 1:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=((p + block_pairs - 1) // block_pairs,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda i, ri, ci: (0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((block_pairs, w), jnp.uint32),
-                pltpu.VMEM((block_pairs, w), jnp.uint32),
-                pltpu.SemaphoreType.DMA((2, block_pairs)),
-            ],
-        )
-        kernel = functools.partial(
-            _gather_total_batched_kernel, block_pairs=block_pairs
-        )
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(p,),
-            in_specs=[
-                # Clamp so padded (-1) entries still produce a legal DMA; the
-                # kernel body masks their contribution to zero.
-                pl.BlockSpec((1, w), lambda i, ri, ci: (jnp.maximum(ri[i], 0), 0)),
-                pl.BlockSpec((1, w), lambda i, ri, ci: (jnp.maximum(ci[i], 0), 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda i, ri, ci: (0, 0)),
-        )
-        kernel = _gather_total_kernel
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        interpret=interpret,
-    )(row_idx, col_idx, row_data, col_data)
-    return out[0, 0]
-
-
-def _gather_segment_kernel(
-    ridx_ref, cidx_ref, row_ref, col_ref, out_ref, *, bucket: int
-):
-    """One pair per grid step, accumulated into its graph's output segment.
-
-    The cross-graph fused variant of ``_gather_total_kernel``: the flat pair
-    index arrays are ``G`` back-to-back ``bucket``-wide segments (one per
-    fused graph), and the out BlockSpec's index map routes step ``p`` to
-    output row ``p // bucket`` — the grid walks segments in order, so each
-    output row is initialized on its segment's first step and accumulated
-    for the rest, giving ``G`` independent int32 subtotals in ONE dispatch.
-    """
-    p = pl.program_id(0)
-    valid = (ridx_ref[p] >= 0) & (cidx_ref[p] >= 0)
-    x = row_ref[...] & col_ref[...]
-    partial = jnp.where(valid, swar_popcount_u32(x).sum(), 0)
-    lane = p % bucket
-
-    @pl.when(lane == 0)
-    def _init():
-        out_ref[0, 0] = partial
-
-    @pl.when(lane != 0)
-    def _acc():
-        out_ref[0, 0] += partial
+    # One segment spanning the padded index arrays: the padding is -1.
+    padded = -(-p // INDEX_BLOCK) * INDEX_BLOCK
+    return _segment_totals(
+        row_data, col_data, row_idx, col_idx, padded, interpret
+    )[0]
 
 
 @functools.partial(jax.jit, static_argnames=("bucket", "interpret"))
@@ -254,32 +254,18 @@ def gather_segment_totals_pallas(
     case ``bucket * W * 32`` must fit int32 (callers bound it — see
     ``kernels/ops.py``).
     """
+    _check_operands(row_data, col_data, row_idx, col_idx)
     p = row_idx.shape[0]
-    assert row_idx.shape == col_idx.shape, (row_idx.shape, col_idx.shape)
-    assert row_data.ndim == col_data.ndim == 2
-    w = row_data.shape[1]
-    assert col_data.shape[1] == w, (row_data.shape, col_data.shape)
-    if bucket < 1 or p % bucket:
-        raise ValueError(f"{p} pairs do not tile into bucket={bucket} segments")
+    if bucket < 1 or bucket & (bucket - 1) or p % bucket:
+        raise ValueError(
+            f"{p} pairs do not tile into power-of-two bucket={bucket} segments"
+        )
     g = p // bucket
     if g == 0:
         return jnp.zeros((0,), jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, w), lambda i, ri, ci: (jnp.maximum(ri[i], 0), 0)),
-            pl.BlockSpec((1, w), lambda i, ri, ci: (jnp.maximum(ci[i], 0), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, ri, ci: (i // bucket, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_gather_segment_kernel, bucket=bucket),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((g, 1), jnp.int32),
-        interpret=interpret,
-    )(row_idx, col_idx, row_data, col_data)
-    return out[:, 0]
+    return _segment_totals(
+        row_data, col_data, row_idx, col_idx, bucket, interpret
+    )[:g]
 
 
 def gather_segment_totals_reference(
@@ -335,8 +321,9 @@ def gather_total_reference(
 def modeled_hbm_bytes(num_pairs: int, words_per_slice: int, *, fused: bool) -> int:
     """Analytic HBM traffic of the execute stage for ``num_pairs`` work items.
 
-    fused:    indices in, each gathered slice word crosses HBM exactly once
-              (store -> VMEM), scalar out.
+    fused:    the Pallas kernel: indices in, one whole ``(W, 128)`` store
+              tile DMA'd to VMEM per operand of every pair (128x the words
+              the pair uses), scalar out.
     unfused:  XLA gather reads the store words *and writes* ``[P, W]``
               operand buffers, then the reduction kernel reads them back —
               3x the gathered-word traffic plus the same index traffic.
@@ -346,5 +333,5 @@ def modeled_hbm_bytes(num_pairs: int, words_per_slice: int, *, fused: bool) -> i
     index = 2 * num_pairs * 4
     out = 4
     if fused:
-        return gathered + index + out
+        return LANES * gathered + index + out
     return 3 * gathered + index + out

@@ -323,9 +323,9 @@ def test_candidate_overflow_guard_and_auto_fallback(monkeypatch):
     g = build_graph(edges, reorder=True)
     want = triangles_intersection(g)
     monkeypatch.setattr(build_mod, "_CAND_GUARD", 1.0)
-    with pytest.raises(ValueError, match="host"):
+    with pytest.raises(build_mod.DeviceCapacityError, match="host"):
         device_build(edges)
-    with pytest.raises(ValueError, match="host"):
+    with pytest.raises(build_mod.DeviceCapacityError, match="host"):
         tcim_count(edges, build="device")
     # Pretend we're on an accelerator so 'auto' resolves to the device
     # build, then let the (monkeypatched) guard reject it: the count must
@@ -336,6 +336,22 @@ def test_candidate_overflow_guard_and_auto_fallback(monkeypatch):
     res = tcim_count(edges, build="auto", backend="jnp")
     assert res.triangles == want
     assert res.stats["build"] == "host"
+
+
+def test_auto_build_surfaces_device_faults(monkeypatch):
+    """build='auto' answers only the capacity refusal with the host build:
+    any other device-build error (a kernel the compiler refuses, a device
+    fault) must surface, not be hidden behind a host count."""
+    from repro.core import build as build_mod
+    from repro.core import tcim as tcim_mod
+
+    def broken(*args, **kwargs):
+        raise ValueError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(tcim_mod.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(build_mod, "device_build", broken)
+    with pytest.raises(ValueError, match="Mosaic"):
+        tcim_count(rmat(300, 1500, seed=29), build="auto", backend="jnp")
 
 
 def test_window_searchsorted_empty_concat():

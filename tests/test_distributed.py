@@ -238,6 +238,45 @@ def test_sharded_2d_single_device_mesh():
     clear_sharded_executor_cache()
 
 
+def test_sharded_executors_take_explicit_axis_meshes():
+    """``jax.make_mesh`` builds Explicit axes. The sharded executors run on
+    the mesh's Auto view, so the streaming store scatter still resolves its
+    shardings, and every mesh path reports the implementation it ran."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.core import build_sbf, build_worklist, tcim_count_graph, update_sbf
+    from repro.distributed import ShardedColsExecutor, Sharded2DExecutor
+    from repro.distributed.tc import MESH_EXECUTE_IMPL
+    from repro.graphs import build_graph, rmat
+    from repro.graphs.exact import triangles_intersection
+
+    g = build_graph(rmat(400, 2500, seed=1), reorder=False)
+    sbf = build_sbf(g, 64)
+    mesh = jax.make_mesh((1, 1), ("r", "c"), axis_types=(AxisType.Explicit,) * 2)
+    ex = Sharded2DExecutor(sbf, mesh, chunk_pairs=256)
+    assert all(t == AxisType.Auto for t in ex.mesh.axis_types)
+    assert ex.mesh.axis_names == mesh.axis_names
+    removed = g.edges[:40]
+    upd = update_sbf(sbf, None, removed)
+    assert not upd.grew
+    ex.update_stores(upd.sbf, upd.row_lanes, upd.col_lanes)
+    keep = ~np.isin(
+        g.edges[:, 0] * g.n + g.edges[:, 1], removed[:, 0] * g.n + removed[:, 1]
+    )
+    g2 = build_graph(g.edges[keep], n=g.n, reorder=False)
+    assert ex.count(build_worklist(g2, upd.sbf)) == triangles_intersection(g2)
+    assert ex.execute_impl == MESH_EXECUTE_IMPL
+    flat = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Explicit,))
+    assert ShardedColsExecutor(sbf, flat).execute_impl == MESH_EXECUTE_IMPL
+    want = triangles_intersection(g)
+    for placement, m in (("sharded_2d", mesh), ("sharded_cols", flat)):
+        res = tcim_count_graph(g, placement=placement, mesh=m)
+        assert res.triangles == want, placement
+        assert res.stats["placement"] == placement
+        assert res.stats["execute_impl"] == MESH_EXECUTE_IMPL, placement
+
+
 def test_pooled_sharded_executor_config_not_aliased():
     """Satellite regression: the pooled sharded caches dropped double_buffer
     (and now schedule) from their keys, so a hit could hand back an executor

@@ -15,6 +15,9 @@ from repro.graphs import build_graph, rmat
 from repro.graphs.exact import triangles_intersection
 from repro.kernels import ops
 from repro.kernels.tc_gather_popcount import (
+    _segment_totals,
+    gather_segment_totals_pallas,
+    gather_segment_totals_reference,
     gather_total_pallas,
     gather_total_reference,
     modeled_hbm_bytes,
@@ -112,7 +115,7 @@ def test_single_trace_across_chunks(small_graph):
 
 
 def test_kernel_matches_mirror_and_oracle(small_graph):
-    """Scalar-prefetch Pallas kernel (interpret) == jnp mirror == oracle."""
+    """Pallas gather kernel (interpret) == jnp mirror == oracle."""
     _, sbf, wl = small_graph
     row_data = jnp.asarray(sbf.row_slice_data)
     col_data = jnp.asarray(sbf.col_slice_data)
@@ -127,8 +130,8 @@ def test_kernel_matches_mirror_and_oracle(small_graph):
 
 @pytest.mark.parametrize("block_pairs", [2, 8, 16])
 def test_batched_kernel_matches_mirror(small_graph, block_pairs):
-    """block_pairs>1 (in-kernel DMA loop): identical totals to the mirror on
-    ragged grids (P not a multiple of B) with negative-index padding."""
+    """B pairs' tile DMAs in flight together: identical totals to the mirror
+    when P is not a multiple of B, with negative-index padding mid-batch."""
     _, sbf, wl = small_graph
     row_data = jnp.asarray(sbf.row_slice_data)
     col_data = jnp.asarray(sbf.col_slice_data)
@@ -136,11 +139,13 @@ def test_batched_kernel_matches_mirror(small_graph, block_pairs):
         ridx = np.asarray(wl.pair_row_pos[:sub], dtype=np.int32).copy()
         cidx = np.asarray(wl.pair_col_pos[:sub], dtype=np.int32).copy()
         ridx[::5] = -1  # padding sentinels interleaved mid-block
+        # One segment spanning the 1024-padded index block, as
+        # gather_total_pallas runs it, with a batch size of this case's.
         got = int(
-            gather_total_pallas(
+            _segment_totals(
                 row_data, col_data, jnp.asarray(ridx), jnp.asarray(cidx),
-                interpret=True, block_pairs=block_pairs,
-            )
+                1024, True, block_pairs=block_pairs,
+            )[0]
         )
         want = int(
             gather_total_reference(
@@ -148,6 +153,62 @@ def test_batched_kernel_matches_mirror(small_graph, block_pairs):
             )
         )
         assert got == want, (block_pairs, sub)
+
+
+@pytest.mark.parametrize(
+    "words,graphs,bucket,block_pairs",
+    [(1, 4, 64, 16), (2, 3, 512, 128), (2, 2, 2048, 128), (4, 5, 16, 8),
+     (8, 1, 1024, 1024)],
+)
+def test_segment_kernel_matches_mirror(words, graphs, bucket, block_pairs):
+    """The segment kernel (interpret) == its jnp mirror, per segment, over
+    store sizes that are not multiples of a tile, buckets below and above
+    one 1024-pair index block, and sentinel lanes mid-segment."""
+    rng = np.random.default_rng(words * 1000 + bucket)
+    rows, cols = 300, 1000  # not multiples of 128: the tile view pads
+    row_data = jnp.asarray(rng.integers(0, 2**32, (rows, words), dtype=np.uint32))
+    col_data = jnp.asarray(rng.integers(0, 2**32, (cols, words), dtype=np.uint32))
+    p = graphs * bucket
+    ridx = rng.integers(0, rows, p).astype(np.int32)
+    cidx = rng.integers(0, cols, p).astype(np.int32)
+    ridx[::7] = -1
+    cidx[::11] = -1
+    got = _segment_totals(
+        row_data, col_data, jnp.asarray(ridx), jnp.asarray(cidx), bucket, True,
+        block_pairs=block_pairs,
+    )[:graphs]
+    want = gather_segment_totals_reference(
+        row_data, col_data, jnp.asarray(ridx), jnp.asarray(cidx), bucket=bucket
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    public = gather_segment_totals_pallas(
+        row_data, col_data, jnp.asarray(ridx), jnp.asarray(cidx),
+        bucket=bucket, interpret=True,
+    )
+    assert public.shape == (graphs,)
+    np.testing.assert_array_equal(np.asarray(public), np.asarray(want))
+
+
+def test_segment_kernel_rejects_bad_tiling():
+    store = jnp.zeros((8, 2), jnp.uint32)
+    idx = jnp.zeros((24,), jnp.int32)
+    with pytest.raises(ValueError, match="bucket"):
+        gather_segment_totals_pallas(store, store, idx, idx, bucket=12)
+    with pytest.raises(ValueError, match="block_pairs"):
+        _segment_totals(store, store, idx, idx, 8, True, block_pairs=3)
+
+
+def test_execute_impl_reports_the_kernel_choice(small_graph):
+    """Fused executors name what runs each chunk; ``use_kernel=None`` is
+    the mirror only off a TPU."""
+    _, sbf, wl = small_graph
+    assert Executor(sbf).execute_impl == (
+        "pallas" if ops.gather_kernel_selected() else "jnp_mirror"
+    )
+    kernel = Executor(sbf, use_kernel=True)
+    assert kernel.execute_impl == "pallas"
+    assert kernel.count(wl) == Executor(sbf, mode="jnp").count(wl)
+    assert Executor(sbf, mode="jnp").execute_impl == "jnp"
 
 
 def test_kernel_negative_index_noop(small_graph):
@@ -216,9 +277,12 @@ def test_distributed_stripe_split_matches_exact(small_graph, monkeypatch):
 
 
 def test_modeled_hbm_bytes_fused_advantage():
-    """The fused path's modeled traffic is the 1-pass bound; unfused is 3x."""
+    """The fused kernel moves one whole (W, 128) tile per operand, so its
+    modeled traffic is 128x the gathered words; unfused moves the words 3x.
+    The fused path's advantage is no [P, W] buffer, not fewer bytes."""
     fused = modeled_hbm_bytes(1000, 2, fused=True)
     unfused = modeled_hbm_bytes(1000, 2, fused=False)
     gathered = 2 * 1000 * 2 * 4
-    assert fused == gathered + 2 * 1000 * 4 + 4
-    assert unfused - fused == 2 * gathered
+    index_and_out = 2 * 1000 * 4 + 4
+    assert fused == 128 * gathered + index_and_out
+    assert unfused == 3 * gathered + index_and_out

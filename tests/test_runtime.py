@@ -1,4 +1,6 @@
 """Checkpointing, fault tolerance, straggler detection, elastic planning."""
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -222,3 +224,33 @@ def test_restore_with_shardings_onto_mesh(tmp_path, rng):
     assert isinstance(leaf, jax.Array)
     assert leaf.sharding.mesh.shape == {"rows": 1, "cols": 1}
     np.testing.assert_array_equal(np.asarray(leaf), tree["a"])
+
+
+def test_compile_cache_dir_env_wins_else_checkout(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and no other
+    directory is configured; otherwise the cache is the fixed directory in
+    the checkout. Thresholds drop so sub-second kernel compiles cache."""
+    import jax
+
+    from repro.runtime.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)

@@ -17,9 +17,6 @@ the legacy gather-then-kernel path at two levels:
     device accumulator, one host sync) vs the old per-chunk ``int()``-sync
     loop with its ragged-tail retrace.
 
-``hbm=`` derived fields carry the modeled execute-stage HBM bytes (see
-``tc_gather_popcount.modeled_hbm_bytes``).
-
 ``--chip`` times the execute kernel alone on a TPU: the Pallas gather
 kernel against the jnp mirror, in ns per pair, over com-youtube worklist
 pairs at published size and over as many uniformly random pairs.
@@ -38,7 +35,6 @@ from benchmarks.common import emit
 from repro.core.executor import Executor
 from repro.core.sbf import SlicedBitmap
 from repro.kernels import ops, ref
-from repro.kernels.tc_gather_popcount import modeled_hbm_bytes
 from repro.runtime.compile_cache import enable_compile_cache
 
 
@@ -110,11 +106,7 @@ def run() -> None:
         lambda rd, cd, r, c: ops.popcount_and_gather_total(rd, cd, r, c)
     )
     us_f = _time(fused, row_data, col_data, ridx, cidx, iters=10)
-    emit(
-        "execute/fused_gather_popcount_64kpairs",
-        us_f,
-        f"hbm={modeled_hbm_bytes(p, w, fused=True)}",
-    )
+    emit("execute/fused_gather_popcount_64kpairs", us_f, "")
     unfused = jax.jit(
         lambda rd, cd, r, c: ops.popcount_and_total(
             jnp.take(rd, r, axis=0), jnp.take(cd, c, axis=0)
@@ -124,7 +116,6 @@ def run() -> None:
     emit(
         "execute/unfused_gather_then_kernel_64kpairs",
         us_u,
-        f"hbm={modeled_hbm_bytes(p, w, fused=False)};"
         f"fused_speedup={us_u / max(us_f, 1e-9):.2f}x",
     )
 
@@ -144,7 +135,7 @@ def run() -> None:
     emit(
         "executor/fused_multichunk_200kpairs",
         us_ex,
-        f"chunks=4;host_syncs=1;double_buffer=1;hbm={ex.modeled_hbm_bytes(pm)}",
+        "chunks=4;host_syncs=1;double_buffer=1",
     )
     us_ser = _time_host(lambda: ex_serial.execute_indices(rpos, cpos), iters=5)
     emit(
@@ -159,7 +150,7 @@ def run() -> None:
     emit(
         "executor/legacy_perchunk_sync_200kpairs",
         us_old,
-        f"chunks=4;host_syncs=4;hbm={ex.modeled_hbm_bytes(pm, fused=False)};"
+        f"chunks=4;host_syncs=4;"
         f"fused_speedup={us_old / max(us_ex, 1e-9):.2f}x",
     )
 

@@ -12,9 +12,8 @@ Columns reproduced:
                  backend; vectorized mirror on CPU, Mosaic kernel on TPU).
   * unfused_s  — same pipeline with the legacy gather-then-kernel execute
                  stage (operands travel to the compute — the anti-pattern
-                 the fused executor removes); the exec_*/hbm_* derived
-                 fields put the execute-stage time and modeled HBM traffic
-                 of the two side by side.
+                 the fused executor removes); the exec_* derived fields
+                 put the execute-stage time of the two side by side.
   * exec_buffered_s / exec_serial_s — steady-state execute-stage time with
                  and without async double-buffering (chunk i+1's index
                  upload overlapping chunk i's kernel).
@@ -38,7 +37,6 @@ from repro.core.cachesim import simulate_lru
 from repro.core.energymodel import PAPER_TABLE5, tcim_latency_energy
 from repro.core.executor import Executor
 from repro.core.tcim import tcim_count_graph
-from repro.kernels.tc_gather_popcount import modeled_hbm_bytes
 from repro.runtime.compile_cache import enable_compile_cache
 
 
@@ -87,9 +85,6 @@ def run(names=None) -> list[dict]:
             name, res.triangles, tri_cpu, res_f.triangles, res_u.triangles)
         assert res.triangles == tri_buf == tri_ser == res_s.triangles, (
             name, res.triangles, tri_buf, tri_ser, res_s.triangles)
-        wps = sbf.words_per_slice
-        hbm_f = modeled_hbm_bytes(wl.num_pairs, wps, fused=True)
-        hbm_u = modeled_hbm_bytes(wl.num_pairs, wps, fused=False)
         exec_f = res_f.timings_s["execute"]
         exec_u = res_u.timings_s["execute"]
         paper = PAPER_TABLE5.get(name, (None,) * 5)
@@ -97,7 +92,7 @@ def run(names=None) -> list[dict]:
             f"triangles={res.triangles};cpu_s={t_cpu.s:.3f};wo_pim_s={t_wo.s:.3f};"
             f"tcim_model_s={tcim_s:.4f};fused_s={t_fused.s:.3f};"
             f"unfused_s={t_unf.s:.3f};exec_fused_s={exec_f:.4f};"
-            f"exec_unfused_s={exec_u:.4f};hbm_fused={hbm_f};hbm_unfused={hbm_u};"
+            f"exec_unfused_s={exec_u:.4f};"
             f"exec_buffered_s={t_buf.s:.4f};exec_serial_s={t_ser.s:.4f};"
             f"sharded_s={t_sh.s:.3f};nshards={nshards};"
             f"build_host_s={t_bhost.s:.4f};build_device_s={t_bdev.s:.4f};"
@@ -118,8 +113,6 @@ def run(names=None) -> list[dict]:
                 "unfused_s": t_unf.s,
                 "exec_fused_s": exec_f,
                 "exec_unfused_s": exec_u,
-                "hbm_fused_bytes": hbm_f,
-                "hbm_unfused_bytes": hbm_u,
                 "exec_buffered_s": t_buf.s,
                 "exec_serial_s": t_ser.s,
                 "sharded_s": t_sh.s,

@@ -40,13 +40,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 
 import numpy as np
 
 from repro.core import sbf as sbf_mod
 from repro.core.plan import pow2_ceil
 from repro.runtime.contracts import max_transfers, no_host_sync
+from repro.runtime.spans import span
 from repro.graphs.csr import (
     DeviceGraph,
     Graph,
@@ -156,30 +156,31 @@ def _get_jits() -> dict:
     def worklist_step(src, dst, m, row_ptr, row_idx, col_ptr, col_idx, cb):
         """Expand row slices per edge, test column membership, compact hits.
 
-        ``cb`` is the static candidate bucket. The binary search runs a
-        fixed iteration count (enough to fully converge any window within
-        the column store), replicating ``_window_searchsorted``'s
-        lower-bound loop branchlessly.
+        The three phases run under the named scopes ``tc_expand``,
+        ``tc_search`` and ``tc_compact``, which every operation's metadata
+        carries into the profiler's trace. ``cb`` is the static candidate
+        bucket. The binary search runs a fixed iteration count (enough to
+        fully converge any window within the column store), replicating
+        ``_window_searchsorted``'s lower-bound loop branchlessly.
         """
         bucket = src.shape[0]
         n = row_ptr.shape[0] - 1
-        valid = jnp.arange(bucket, dtype=jnp.int32) < m
-        u = jnp.clip(src, 0, n - 1)
-        cnt = jnp.where(valid, row_ptr[u + 1] - row_ptr[u], 0)
-        cum = jnp.cumsum(cnt)
-        start = cum - cnt
-        total = cum[-1]
-        lane = jnp.arange(cb, dtype=jnp.int32)
-        e = jnp.minimum(
-            jnp.searchsorted(cum, lane, side="right").astype(jnp.int32),
-            bucket - 1,
-        )
-        lane_valid = lane < total
-        row_pos = row_ptr[u[e]] + (lane - start[e])
-        ks = row_idx[jnp.clip(row_pos, 0, row_idx.shape[0] - 1)]
-        v = jnp.clip(dst[e], 0, n - 1)
-        lo, hi = col_ptr[v], col_ptr[v + 1]
         col_cap = col_idx.shape[0]
+        with jax.named_scope("tc_expand"):
+            valid = jnp.arange(bucket, dtype=jnp.int32) < m
+            u = jnp.clip(src, 0, n - 1)
+            cnt = jnp.where(valid, row_ptr[u + 1] - row_ptr[u], 0)
+            cum = jnp.cumsum(cnt)
+            start = cum - cnt
+            total = cum[-1]
+            lane = jnp.arange(cb, dtype=jnp.int32)
+            e = jnp.minimum(
+                jnp.searchsorted(cum, lane, side="right").astype(jnp.int32),
+                bucket - 1,
+            )
+            lane_valid = lane < total
+            row_pos = row_ptr[u[e]] + (lane - start[e])
+            ks = row_idx[jnp.clip(row_pos, 0, row_idx.shape[0] - 1)]
 
         def body(_, lh):
             lo_w, hi_w = lh
@@ -191,18 +192,22 @@ def _get_jits() -> dict:
             hi_w = jnp.where(active & ~go_right, mid, hi_w)
             return lo_w, hi_w
 
-        pos, _ = jax.lax.fori_loop(
-            0, int(col_cap).bit_length() + 1, body, (lo, hi)
-        )
-        hit = lane_valid & (pos < hi) & (
-            col_idx[jnp.minimum(pos, col_cap - 1)] == ks
-        )
-        out = jnp.cumsum(hit.astype(jnp.int32)) - 1
-        tgt = jnp.where(hit, out, cb)  # misses scatter-drop
-        pe = jnp.full(cb, -1, jnp.int32).at[tgt].set(e, mode="drop")
-        pr = jnp.full(cb, -1, jnp.int32).at[tgt].set(row_pos, mode="drop")
-        pc = jnp.full(cb, -1, jnp.int32).at[tgt].set(pos, mode="drop")
-        return pe, pr, pc, jnp.sum(hit.astype(jnp.int32))
+        with jax.named_scope("tc_search"):
+            v = jnp.clip(dst[e], 0, n - 1)
+            lo, hi = col_ptr[v], col_ptr[v + 1]
+            pos, _ = jax.lax.fori_loop(
+                0, int(col_cap).bit_length() + 1, body, (lo, hi)
+            )
+            hit = lane_valid & (pos < hi) & (
+                col_idx[jnp.minimum(pos, col_cap - 1)] == ks
+            )
+        with jax.named_scope("tc_compact"):
+            out = jnp.cumsum(hit.astype(jnp.int32)) - 1
+            tgt = jnp.where(hit, out, cb)  # misses scatter-drop
+            pe = jnp.full(cb, -1, jnp.int32).at[tgt].set(e, mode="drop")
+            pr = jnp.full(cb, -1, jnp.int32).at[tgt].set(row_pos, mode="drop")
+            pc = jnp.full(cb, -1, jnp.int32).at[tgt].set(pos, mode="drop")
+            return pe, pr, pc, jnp.sum(hit.astype(jnp.int32))
 
     @functools.partial(jax.jit, static_argnums=(1,))
     def prefix(a, k):
@@ -332,7 +337,8 @@ def _make_worklist(
         dg.src, dg.dst, dg.m_dev,
         sb.row_ptr, sb.row_slice_idx, sb.col_ptr, sb.col_slice_idx, cb,
     )
-    num_pairs = int(npair)  # scalar readback sizes the pair bucket
+    with span("tc.schedule.pair_wait"):
+        num_pairs = int(npair)  # scalar readback sizes the pair bucket
     pb = pow2_ceil(max(num_pairs, 1))
     return DeviceWorklist(
         pair_edge=jits["prefix"](pe, pb),
@@ -368,15 +374,15 @@ class DeviceBuildFuture:
         if self._build is None:
             import jax.numpy as jnp
 
-            t0 = time.perf_counter()
             raw = self._raw
-            # tclint: sync-ok(the build's one sizing readback, at future close)
-            sizes = np.asarray(jnp.stack([raw[3], raw[7], raw[8], raw[9]]))
-            row_nvs, col_nvs, cand = (int(x) for x in sizes[:3])
-            cand_shadow = float(sizes[3:].view(np.float32)[0])
-            sb = _finalize_sbf(self._dg, self._slice_bits, raw, row_nvs, col_nvs)
-            wl = _make_worklist(self._dg, sb, cand, cand_shadow)
-            self.timings_s["schedule"] = time.perf_counter() - t0
+            with span("tc.schedule", self.timings_s, "schedule"):
+                with span("tc.schedule.size_wait"):
+                    # tclint: sync-ok(the build's one sizing readback, at future close)
+                    sizes = np.asarray(jnp.stack([raw[3], raw[7], raw[8], raw[9]]))
+                row_nvs, col_nvs, cand = (int(x) for x in sizes[:3])
+                cand_shadow = float(sizes[3:].view(np.float32)[0])
+                sb = _finalize_sbf(self._dg, self._slice_bits, raw, row_nvs, col_nvs)
+                wl = _make_worklist(self._dg, sb, cand, cand_shadow)
             self._build = DeviceBuild(
                 graph=self._dg, sbf=sb, worklist=wl, timings_s=self.timings_s
             )
@@ -387,9 +393,8 @@ class DeviceBuildFuture:
 def _dispatch_sbf(dg: DeviceGraph, slice_bits: int, timings: dict) -> DeviceBuildFuture:
     if slice_bits % 32 != 0:
         raise ValueError("slice_bits must be a multiple of 32")
-    t0 = time.perf_counter()
-    raw = _get_jits()["sbf"](dg.src, dg.dst, dg.m_dev, dg.n, slice_bits)
-    timings["compress"] = time.perf_counter() - t0
+    with span("tc.compress", timings, "compress"):
+        raw = _get_jits()["sbf"](dg.src, dg.dst, dg.m_dev, dg.n, slice_bits)
     return DeviceBuildFuture(dg, slice_bits, raw, timings)
 
 
@@ -409,9 +414,8 @@ def device_build_async(
     sizing readback happens in ``DeviceBuildFuture.result()``.
     """
     timings: dict = {}
-    t0 = time.perf_counter()
-    dg = device_orient(edges, n, reorder=reorder)
-    timings["orient"] = time.perf_counter() - t0
+    with span("tc.orient", timings, "orient"):
+        dg = device_orient(edges, n, reorder=reorder)
     return _dispatch_sbf(dg, slice_bits, timings)
 
 
@@ -436,9 +440,8 @@ def device_build_graph_async(g: Graph, slice_bits: int = 64) -> DeviceBuildFutur
     and the host ``build_sbf``/``build_worklist`` bit for bit.
     """
     timings: dict = {}
-    t0 = time.perf_counter()
-    dg = device_orient(g.edges, n=g.n, reorder=False)
-    timings["orient"] = time.perf_counter() - t0
+    with span("tc.orient", timings, "orient"):
+        dg = device_orient(g.edges, n=g.n, reorder=False)
     return _dispatch_sbf(dg, slice_bits, timings)
 
 

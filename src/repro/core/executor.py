@@ -87,7 +87,6 @@ from repro.core import sbf as sbf_mod
 from repro.core.plan import clamp_chunk_pairs, plan_fusion, pow2_ceil as _pow2_ceil
 from repro.kernels import ops, ref
 from repro.kernels.common import on_cpu
-from repro.kernels.tc_gather_popcount import modeled_hbm_bytes
 from repro.runtime.contracts import max_transfers, no_host_sync
 
 __all__ = [
@@ -342,11 +341,11 @@ def _chunk_step_fn(
             )
         return ref.ref_popcount_and_total(rows, cols)  # 'jnp' oracle path
 
-    def step(row_data, col_data, ridx, cidx, acc):
+    def tc_chunk_step(row_data, col_data, ridx, cidx, acc):
         return acc + chunk_total(row_data, col_data, ridx, cidx)
 
     argnums = {"none": (), "acc": (4,), "all": (2, 3, 4)}[donate]
-    return jax.jit(step, donate_argnums=argnums)
+    return jax.jit(tc_chunk_step, donate_argnums=argnums)
 
 
 class Executor:
@@ -611,12 +610,6 @@ class Executor:
         self.row_data = self._adopt_store(sb.row_slice_data, True)
         self.col_data = self._adopt_store(sb.col_slice_data, True)
 
-    def modeled_hbm_bytes(self, num_pairs: int, *, fused: bool | None = None) -> int:
-        """Modeled execute-stage HBM traffic for this store's word width."""
-        if fused is None:
-            fused = self.mode == "fused"
-        return modeled_hbm_bytes(num_pairs, self.words_per_slice, fused=fused)
-
 
 def sbf_content_key(sb: sbf_mod.SlicedBitmap) -> str:
     """Digest of an SBF's store contents (shape + data).
@@ -845,13 +838,13 @@ def _fused_step_fn(bucket: int, interpret: bool | None, use_kernel: bool | None)
     re-execute their resident index blocks.
     """
 
-    def step(row_data, col_data, ridx, cidx):
+    def tc_fused_step(row_data, col_data, ridx, cidx):
         return ops.popcount_and_gather_segment_totals(
             row_data, col_data, ridx, cidx,
             bucket=bucket, use_kernel=use_kernel, interpret=interpret,
         )
 
-    return jax.jit(step)
+    return jax.jit(tc_fused_step)
 
 
 def _worklist_key(wl) -> str:

@@ -57,7 +57,6 @@ the sharded executor.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import numpy as np
@@ -70,6 +69,7 @@ from repro.core.executor import Executor
 from repro.core.plan import pow2_ceil
 from repro.graphs.csr import build_graph
 from repro.runtime.contracts import max_retrace
+from repro.runtime.spans import span
 
 __all__ = [
     "DeltaResult",
@@ -523,9 +523,15 @@ class StreamingTCState:
         canonicalized here). Set semantics are enforced: adds must be
         absent, removes present, no edge in both, no self-loops, vertices
         within the fixed universe. Empty batches are free no-ops.
+        ``timings_s`` holds the host's seconds in each step (the dispatch,
+        for a step that only enqueues device work) and ``total``; each step
+        runs under a ``tc.delta.<key>`` span inside one ``tc.delta`` span.
         """
-        t_start = time.perf_counter()
         timings: dict[str, float] = {}
+        with span("tc.delta", timings, "total"):
+            return self._apply_batch(added, removed, timings)
+
+    def _apply_batch(self, added, removed, timings: dict) -> DeltaResult:
         n = np.int64(self.n)
         a = _orient_batch(_as_edge_array(added), self.n, "added")
         r = _orient_batch(_as_edge_array(removed), self.n, "removed")
@@ -534,7 +540,7 @@ class StreamingTCState:
             return DeltaResult(
                 triangles=self.triangles, delta=0, added=0, removed=0,
                 touched_edges=0, pairs_before=0, pairs_after=0, grew=False,
-                timings_s={"total": time.perf_counter() - t_start},
+                timings_s=timings,
             )
         ka = a[:, 0] * n + a[:, 1]
         kr = r[:, 0] * n + r[:, 1]
@@ -546,22 +552,63 @@ class StreamingTCState:
         vc = np.unique(np.concatenate([a[:, 1], r[:, 1]]))
 
         # Before count: touched edges of the OLD edge set vs the OLD stores.
-        t0 = time.perf_counter()
-        src_b, dst_b = self._touched(self._keys, self._keys_t, vr, vc)
-        wl_before = self._delta_worklist(src_b, dst_b, self._sbf)
-        timings["schedule_before"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        with self._steady_guard(self._count_sig(wl_before)):
-            fut_before = self.executor.count_async(wl_before)
-        timings["dispatch_before"] = time.perf_counter() - t0
+        with span("tc.delta.schedule_before", timings, "schedule_before"):
+            src_b, dst_b = self._touched(self._keys, self._keys_t, vr, vc)
+            wl_before = self._delta_worklist(src_b, dst_b, self._sbf)
+        with span("tc.delta.dispatch_before", timings, "dispatch_before"):
+            with self._steady_guard(self._count_sig(wl_before)):
+                fut_before = self.executor.count_async(wl_before)
 
-        # Update the host mirror and scatter/adopt the resident stores. The
-        # scatter never donates, so the in-flight before-count keeps its
-        # buffers; growth re-adopts (or rebuilds the sharded executor).
-        t0 = time.perf_counter()
-        upd = sbf_mod.update_sbf(self._sbf, a, r)
-        timings["update"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
+        # Update the host mirror and scatter/adopt the resident stores.
+        with span("tc.delta.update", timings, "update"):
+            upd = sbf_mod.update_sbf(self._sbf, a, r)
+        with span("tc.delta.scatter", timings, "scatter"):
+            self._scatter(upd)
+
+        # Merge the sorted edge-key arrays (both orientations).
+        with span("tc.delta.merge", timings, "merge"):
+            keys = np.concatenate([self._keys, ka])
+            keys.sort(kind="stable")
+            if len(kr):
+                keys = np.delete(keys, np.searchsorted(keys, kr))
+            keys_t = np.concatenate([self._keys_t, self._transpose_keys(ka)])
+            keys_t.sort(kind="stable")
+            if len(kr):
+                keys_t = np.delete(
+                    keys_t, np.searchsorted(keys_t, self._transpose_keys(kr))
+                )
+            self._keys, self._keys_t = keys, keys_t
+
+        # After count: touched edges of the NEW edge set vs the NEW stores
+        # (same Vr/Vc — untouched terms cancel exactly in the difference).
+        with span("tc.delta.schedule_after", timings, "schedule_after"):
+            src_a, dst_a = self._touched(self._keys, self._keys_t, vr, vc)
+            wl_after = self._delta_worklist(src_a, dst_a, self._sbf)
+        with span("tc.delta.dispatch_after", timings, "dispatch_after"):
+            with self._steady_guard(self._count_sig(wl_after)):
+                fut_after = self.executor.count_async(wl_after)
+
+        with span("tc.delta.close", timings, "close"):
+            delta = int(fut_after.result()) - int(fut_before.result())
+        self.triangles += delta
+        self.batches += 1
+        return DeltaResult(
+            triangles=self.triangles,
+            delta=delta,
+            added=int(len(a)),
+            removed=int(len(r)),
+            touched_edges=int(len(src_a)),
+            pairs_before=int(wl_before.num_pairs),
+            pairs_after=int(wl_after.num_pairs),
+            grew=bool(upd.grew),
+            timings_s=timings,
+        )
+
+    def _scatter(self, upd) -> None:
+        """Scatter (or adopt, on growth) the updated stores into the
+        resident executor. The scatter never donates, so an in-flight
+        count keeps its buffers; growth re-adopts (or rebuilds the sharded
+        executor)."""
         if self._mesh is not None:
             if upd.grew:
                 self.executor = self._make_sharded(upd.sbf)
@@ -577,51 +624,6 @@ class StreamingTCState:
             with self._steady_guard(("scatter",) + sig + self._store_sig()):
                 self.executor.update_stores(upd.row_lanes, upd.col_lanes)
         self._sbf = upd.sbf
-        timings["scatter"] = time.perf_counter() - t0
-
-        # Merge the sorted edge-key arrays (both orientations).
-        t0 = time.perf_counter()
-        keys = np.concatenate([self._keys, ka])
-        keys.sort(kind="stable")
-        if len(kr):
-            keys = np.delete(keys, np.searchsorted(keys, kr))
-        keys_t = np.concatenate([self._keys_t, self._transpose_keys(ka)])
-        keys_t.sort(kind="stable")
-        if len(kr):
-            keys_t = np.delete(
-                keys_t, np.searchsorted(keys_t, self._transpose_keys(kr))
-            )
-        self._keys, self._keys_t = keys, keys_t
-        timings["merge"] = time.perf_counter() - t0
-
-        # After count: touched edges of the NEW edge set vs the NEW stores
-        # (same Vr/Vc — untouched terms cancel exactly in the difference).
-        t0 = time.perf_counter()
-        src_a, dst_a = self._touched(self._keys, self._keys_t, vr, vc)
-        wl_after = self._delta_worklist(src_a, dst_a, self._sbf)
-        timings["schedule_after"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        with self._steady_guard(self._count_sig(wl_after)):
-            fut_after = self.executor.count_async(wl_after)
-        timings["dispatch_after"] = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        delta = int(fut_after.result()) - int(fut_before.result())
-        timings["close"] = time.perf_counter() - t0
-        self.triangles += delta
-        self.batches += 1
-        timings["total"] = time.perf_counter() - t_start
-        return DeltaResult(
-            triangles=self.triangles,
-            delta=delta,
-            added=int(len(a)),
-            removed=int(len(r)),
-            touched_edges=int(len(src_a)),
-            pairs_before=int(wl_before.num_pairs),
-            pairs_after=int(wl_after.num_pairs),
-            grew=bool(upd.grew),
-            timings_s=timings,
-        )
 
     def verify(self) -> int:
         """From-scratch oracle check: raises on any running-count drift."""

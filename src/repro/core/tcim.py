@@ -25,10 +25,15 @@ stores and worklists flow straight into the pooled Executor with zero host
 bounces (two scalar readbacks size the static output buckets; the bulk
 arrays never travel). ``build='auto'`` picks the device build on
 accelerator backends for the single-device worklist path and the NumPy
-reference elsewhere. Per-stage wall-clock lands in ``TCResult.timings_s``
-(``orient``/``compress``/``schedule``/``plan``/``execute``, plus ``close``
-for async counts and ``materialize`` when a device build feeds a sharded
-mesh path, which repacks stores on the host).
+reference elsewhere. Each stage runs under a named span
+(``repro.runtime.spans``) on the profiler's clock: ``tc.count`` wraps one
+call, with ``tc.orient``/``tc.compress``/``tc.schedule``/``tc.plan``/
+``tc.execute`` (plus ``tc.close`` for async counts and ``tc.materialize``
+when a device build feeds a sharded mesh path, which repacks stores on the
+host) inside it. ``TCResult.timings_s`` holds the host's time in each
+stage under the same keys without the ``tc.`` prefix; for a stage that
+only dispatches device work that is the dispatch, and the stage's device
+time is in the trace.
 
 Backends for the execute stage (mapped onto Executor modes):
     'pallas_total'   fused gather–AND–popcount executor (default; the TCIM
@@ -43,7 +48,7 @@ Backends for the execute stage (mapped onto Executor modes):
 from __future__ import annotations
 
 import dataclasses
-import time
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +66,7 @@ from repro.core.streaming import (  # noqa: F401  (re-exported: streaming API)
 )
 from repro.graphs.csr import Graph, build_graph
 from repro.kernels import ops
+from repro.runtime.spans import next_count_id, span
 
 __all__ = [
     "TCResult",
@@ -110,6 +116,8 @@ class TCResult:
     triangles: int
     backend: str
     stats: dict
+    # Stage -> the host's seconds in it (the dispatch, for a stage that
+    # only enqueues device work); the device's time is in the trace.
     timings_s: dict
 
     def __repr__(self) -> str:  # compact, log-friendly
@@ -122,9 +130,11 @@ class TCFuture:
 
     ``tcim_count*(async_=True)`` returns one of these with every device step
     already enqueued; ``result()`` performs the single host readback (adding
-    its wall-clock as ``timings_s['close']``) and caches the ``TCResult``.
-    Fleet callers overlap graph i's close with graph i+1's build and
-    dispatch. ``stats`` and ``timings_s`` are readable before the close.
+    its wall-clock as ``timings_s['close']``, under a ``tc.close`` span in a
+    ``tc.count`` span that carries the dispatching call's ``count_id``) and
+    caches the ``TCResult``. Fleet callers overlap graph i's close with
+    graph i+1's build and dispatch. ``stats`` and ``timings_s`` are readable
+    before the close.
     """
 
     def __init__(self, future: CountFuture, backend: str, stats: dict, timings_s: dict):
@@ -132,13 +142,15 @@ class TCFuture:
         self.backend = backend
         self.stats = stats
         self.timings_s = timings_s
+        self.count_id = 0
         self._result: TCResult | None = None
 
     def result(self) -> TCResult:
         if self._result is None:
-            t0 = time.perf_counter()
-            triangles = self._future.result()
-            self.timings_s["close"] = time.perf_counter() - t0
+            with span("tc.count", count_id=self.count_id), span(
+                "tc.close", self.timings_s, "close"
+            ):
+                triangles = self._future.result()
             self._result = TCResult(
                 triangles, self.backend, self.stats, self.timings_s
             )
@@ -175,26 +187,15 @@ def _try_device_build(make_build, build: str):
         return None
 
 
-def _execute_worklist_async(
+def _plan_execute(
     sb: sbf_mod.SlicedBitmap,
     wl: sbf_mod.Worklist,
-    backend: str,
     chunk_pairs: int,
     placement: str,
     mesh,
-    pool: ExecutorPool | None,
-    schedule: str,
-) -> tuple[CountFuture, str, float, str]:
-    """Plan and dispatch the execute stage; defer the host readback.
-
-    Resolves ``placement`` against the device topology (the mesh's, when
-    given), then dispatches on a pooled replicated Executor, the
-    column-sharded distributed path, or the 2-D owner-grid path — every
-    branch returns with its steps enqueued and the close deferred to the
-    future. Returns (future, resolved placement, planning seconds, execute
-    implementation). Every mesh path runs the fused jnp mirror inside
-    shard_map.
-    """
+):
+    """Resolve ``placement`` against the device topology (the mesh's, when
+    given) and plan the execute stage."""
     grid = None
     if mesh is not None:
         topo = DeviceTopology(
@@ -214,11 +215,29 @@ def _execute_worklist_async(
             "(e.g. jax.make_mesh((4, 2), ('r', 'c'))) to place the "
             "(row_shard, col_shard) owner grid on"
         )
-    t0 = time.perf_counter()
-    plan = plan_execution(
+    return plan_execution(
         sb, wl, topo, placement=placement, chunk_pairs=chunk_pairs, grid=grid
     )
-    plan_s = time.perf_counter() - t0
+
+
+def _dispatch_plan(
+    sb: sbf_mod.SlicedBitmap,
+    wl: sbf_mod.Worklist,
+    plan,
+    backend: str,
+    chunk_pairs: int,
+    mesh,
+    pool: ExecutorPool | None,
+    schedule: str,
+) -> tuple[CountFuture, str]:
+    """Dispatch the planned execute stage; defer the host readback.
+
+    Dispatches on a pooled replicated Executor, the column-sharded
+    distributed path, or the 2-D owner-grid path — every branch returns
+    with its steps enqueued and the close deferred to the future. Returns
+    (future, execute implementation). Every mesh path runs the fused jnp
+    mirror inside shard_map.
+    """
     if plan.placement == "sharded_2d":
         # Imported here: core stays importable without the distributed layer.
         from repro.distributed.tc import pooled_sharded_2d_executor
@@ -228,7 +247,7 @@ def _execute_worklist_async(
         )
         # count(wl, plan) falls back to the pooled executor's resident
         # bounds when the fresh plan's ranges differ — no store re-upload.
-        return ex.count_async(wl, plan), plan.placement, plan_s, ex.execute_impl
+        return ex.count_async(wl, plan), ex.execute_impl
     if plan.placement == "sharded_cols":
         if mesh is None:
             raise ValueError(
@@ -240,10 +259,8 @@ def _execute_worklist_async(
         ex = pooled_sharded_executor(
             sb, mesh, chunk_pairs=chunk_pairs, schedule=schedule
         )
-        return (
-            ex.count_plan_async(plan), plan.placement, plan_s, ex.execute_impl
-        )
-    if mesh is not None and topo.num_devices > 1:
+        return ex.count_plan_async(plan), ex.execute_impl
+    if mesh is not None and mesh.devices.size > 1:
         # Replicated over a real mesh: stores on every device, work-list
         # stripes dealt across it, scalar psum close. Runs the fused jnp
         # mirror inside shard_map, so `backend` does not apply here.
@@ -252,19 +269,23 @@ def _execute_worklist_async(
             distributed_tc_count_async,
         )
 
-        return (
-            distributed_tc_count_async(
-                sb, wl, mesh, max_step_pairs=plan.chunk_pairs
-            ),
-            plan.placement,
-            plan_s,
-            MESH_EXECUTE_IMPL,
+        fut = distributed_tc_count_async(
+            sb, wl, mesh, max_step_pairs=plan.chunk_pairs
         )
-    # NOT `pool or ...`: an empty ExecutorPool is falsy (it has __len__).
-    ex = (pool if pool is not None else _DEFAULT_POOL).get(
-        sb, mode=_EXECUTOR_MODE[backend], chunk_pairs=chunk_pairs
-    )
-    return ex.count_async(wl), plan.placement, plan_s, ex.execute_impl
+        return fut, MESH_EXECUTE_IMPL
+    ex = _pooled_executor(sb, backend, chunk_pairs, pool)
+    return ex.count_async(wl), ex.execute_impl
+
+
+def _pooled_executor(sb: sbf_mod.SlicedBitmap, backend: str, chunk_pairs: int,
+                     pool: ExecutorPool | None):
+    """The replicated Executor for ``sb`` from ``pool`` (or the module's),
+    store adoption included, under the ``tc.execute.pool`` span."""
+    with span("tc.execute.pool"):
+        # NOT `pool or ...`: an empty ExecutorPool is falsy (it has __len__).
+        return (pool if pool is not None else _DEFAULT_POOL).get(
+            sb, mode=_EXECUTOR_MODE[backend], chunk_pairs=chunk_pairs
+        )
 
 
 def _execute_bitgemm(g: Graph, chunk_rows: int = 2048) -> int:
@@ -321,12 +342,11 @@ def _finish_host(
         from repro.distributed.resilient import resilient_tc_count
         from repro.distributed.tc import MESH_EXECUTE_IMPL
 
-        t0 = time.perf_counter()
-        triangles, rinfo = resilient_tc_count(
-            sb, wl, mesh, resilience, chunk_pairs=chunk_pairs,
-            schedule=schedule,
-        )
-        timings["execute"] = time.perf_counter() - t0
+        with span("tc.execute", timings, "execute"):
+            triangles, rinfo = resilient_tc_count(
+                sb, wl, mesh, resilience, chunk_pairs=chunk_pairs,
+                schedule=schedule,
+            )
         if "step_ewma_s" in rinfo:
             timings["step_ewma_s"] = rinfo["step_ewma_s"]
         stats = (
@@ -344,22 +364,18 @@ def _finish_host(
             fut._result = res
             return fut
         return res
-    t0 = time.perf_counter()
-    fut, resolved, plan_s, impl = _execute_worklist_async(
-        sb, wl, backend, chunk_pairs, placement, mesh, pool, schedule
-    )
-    dispatch_s = time.perf_counter() - t0 - plan_s
-    timings["plan"] = plan_s
+    with span("tc.plan", timings, "plan"):
+        plan = _plan_execute(sb, wl, chunk_pairs, placement, mesh)
     stats = sbf_mod.sbf_stats(g, sb, wl) if collect_stats else {"n": g.n, "m": g.m}
-    stats["placement"] = resolved
+    stats["placement"] = plan.placement
     stats["build"] = build_label
-    stats["execute_impl"] = impl
-    if async_:
-        timings["execute"] = dispatch_s
-        return TCFuture(fut, backend, stats, timings)
-    t0 = time.perf_counter()
-    triangles = fut.result()
-    timings["execute"] = dispatch_s + time.perf_counter() - t0
+    with span("tc.execute", timings, "execute"):
+        fut, stats["execute_impl"] = _dispatch_plan(
+            sb, wl, plan, backend, chunk_pairs, mesh, pool, schedule
+        )
+        if async_:
+            return TCFuture(fut, backend, stats, timings)
+        triangles = fut.result()
     return TCResult(triangles, backend, stats, timings)
 
 
@@ -386,12 +402,6 @@ def _finish_device(
         # the plan stage is trivial, and skipping the planner keeps the
         # worklist arrays on device (plan_execution needs host arrays).
         timings["plan"] = 0.0
-        t0 = time.perf_counter()
-        ex = (pool if pool is not None else _DEFAULT_POOL).get(
-            db.sbf, mode=_EXECUTOR_MODE[backend], chunk_pairs=chunk_pairs
-        )
-        fut = ex.count_async(db.worklist)
-        dispatch_s = time.perf_counter() - t0
         stats = (
             sbf_mod.sbf_stats(db.graph, db.sbf, db.worklist)
             if collect_stats
@@ -399,17 +409,16 @@ def _finish_device(
         )
         stats["placement"] = "replicated"
         stats["build"] = "device"
-        stats["execute_impl"] = ex.execute_impl
-        if async_:
-            timings["execute"] = dispatch_s
-            return TCFuture(fut, backend, stats, timings)
-        t0 = time.perf_counter()
-        triangles = fut.result()
-        timings["execute"] = dispatch_s + time.perf_counter() - t0
+        with span("tc.execute", timings, "execute"):
+            ex = _pooled_executor(db.sbf, backend, chunk_pairs, pool)
+            stats["execute_impl"] = ex.execute_impl
+            fut = ex.count_async(db.worklist)
+            if async_:
+                return TCFuture(fut, backend, stats, timings)
+            triangles = fut.result()
         return TCResult(triangles, backend, stats, timings)
-    t0 = time.perf_counter()
-    sb, wl = db.to_host()
-    timings["materialize"] = time.perf_counter() - t0
+    with span("tc.materialize", timings, "materialize"):
+        sb, wl = db.to_host()
     return _finish_host(
         db.graph, sb, wl,
         backend=backend, chunk_pairs=chunk_pairs, collect_stats=collect_stats,
@@ -419,6 +428,23 @@ def _finish_device(
     )
 
 
+def _counted(fn):
+    """Run each call of ``fn`` under one ``tc.count`` span whose
+    ``count_id`` a returned ``TCFuture`` keeps for its close."""
+
+    @functools.wraps(fn)
+    def counted(*args, **kwargs):
+        count_id = next_count_id()
+        with span("tc.count", count_id=count_id):
+            res = fn(*args, **kwargs)
+        if isinstance(res, TCFuture):
+            res.count_id = count_id
+        return res
+
+    return counted
+
+
+@_counted
 def tcim_count_graph(
     g: Graph,
     *,
@@ -488,12 +514,11 @@ def tcim_count_graph(
 
     if backend in ("bitgemm", "mxu"):
         _resolve_build(build, backend, mesh, g.m)  # validates the request
-        t0 = time.perf_counter()
-        if backend == "mxu":
-            count = int(ops.dense_mxu_tc(jnp.asarray(g.dense_upper())))
-        else:
-            count = _execute_bitgemm(g)
-        timings["execute"] = time.perf_counter() - t0
+        with span("tc.execute", timings, "execute"):
+            if backend == "mxu":
+                count = int(ops.dense_mxu_tc(jnp.asarray(g.dense_upper())))
+            else:
+                count = _execute_bitgemm(g)
         res = TCResult(count, backend, {"n": g.n, "m": g.m}, timings)
         if async_:  # dense paths close eagerly; hand back a resolved future
             fut = TCFuture(CountFuture([count]), backend, res.stats, timings)
@@ -515,13 +540,10 @@ def tcim_count_graph(
             )
         timings = {}  # auto fell back: restart stage timings on the host path
 
-    t0 = time.perf_counter()
-    sb = sbf_mod.build_sbf(g, slice_bits)
-    timings["compress"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    wl = sbf_mod.build_worklist(g, sb)
-    timings["schedule"] = time.perf_counter() - t0
+    with span("tc.compress", timings, "compress"):
+        sb = sbf_mod.build_sbf(g, slice_bits)
+    with span("tc.schedule", timings, "schedule"):
+        wl = sbf_mod.build_worklist(g, sb)
 
     return _finish_host(
         g, sb, wl,
@@ -532,6 +554,7 @@ def tcim_count_graph(
     )
 
 
+@_counted
 def tcim_count(
     edges: np.ndarray,
     *,
@@ -578,10 +601,11 @@ def tcim_count(
                 pool=pool, schedule=schedule, timings={}, async_=async_,
                 resilience=resilience,
             )
-    t0 = time.perf_counter()
-    g = build_graph(edges, n=n, reorder=reorder)
-    t_orient = time.perf_counter() - t0
-    res = tcim_count_graph(
+    orient: dict[str, float] = {}
+    with span("tc.orient", orient, "orient"):
+        g = build_graph(edges, n=n, reorder=reorder)
+    # The undecorated body: this call is already inside its tc.count span.
+    res = tcim_count_graph.__wrapped__(
         g,
         slice_bits=slice_bits,
         backend=backend,
@@ -595,5 +619,5 @@ def tcim_count(
         async_=async_,
         resilience=resilience,
     )
-    res.timings_s = {"orient": t_orient, **res.timings_s}
+    res.timings_s = {**orient, **res.timings_s}
     return res

@@ -145,7 +145,7 @@ def make_tc_step(mesh: Mesh, axis_names: tuple[str, ...]):
     """
     flat = P(axis_names)  # leading dim sharded over every axis
 
-    def step(row_data, col_data, row_idx, col_idx):
+    def tc_mesh_replicated_step(row_data, col_data, row_idx, col_idx):
         def local(row_data, col_data, r, c):
             # r, c: this device's stripe of the flat work list.
             partial = _local_count(row_data, col_data, r, c)
@@ -159,7 +159,7 @@ def make_tc_step(mesh: Mesh, axis_names: tuple[str, ...]):
         )(row_data, col_data, row_idx, col_idx)[0]
 
     return jax.jit(
-        step,
+        tc_mesh_replicated_step,
         in_shardings=(
             NamedSharding(mesh, P()),
             NamedSharding(mesh, P()),
@@ -183,7 +183,7 @@ def make_sharded_cols_step(mesh: Mesh, axis_names: tuple[str, ...]):
     flat = P(axis_names)
     col_spec = P(axis_names, None)
 
-    def step(row_data, col_block, row_idx, col_idx):
+    def tc_mesh_cols_step(row_data, col_block, row_idx, col_idx):
         def local(row_data, col_block, r, c):
             partial = gather_total_reference(row_data, col_block, r, c)
             return jax.lax.psum(partial[None], axis_names)
@@ -196,7 +196,7 @@ def make_sharded_cols_step(mesh: Mesh, axis_names: tuple[str, ...]):
         )(row_data, col_block, row_idx, col_idx)[0]
 
     return jax.jit(
-        step,
+        tc_mesh_cols_step,
         in_shardings=(
             NamedSharding(mesh, P()),
             NamedSharding(mesh, col_spec),
@@ -564,7 +564,7 @@ def make_sharded_2d_step(mesh: Mesh, axis_names: tuple[str, ...]):
     col_spec = P(col_axis, None)
     flat = P(axis_names)
 
-    def step(row_block, col_block, row_idx, col_idx):
+    def tc_mesh_2d_step(row_block, col_block, row_idx, col_idx):
         def local(row_block, col_block, r, c):
             partial = gather_total_reference(row_block, col_block, r, c)
             return jax.lax.psum(partial[None], axis_names)
@@ -577,7 +577,7 @@ def make_sharded_2d_step(mesh: Mesh, axis_names: tuple[str, ...]):
         )(row_block, col_block, row_idx, col_idx)[0]
 
     return jax.jit(
-        step,
+        tc_mesh_2d_step,
         in_shardings=(
             NamedSharding(mesh, row_spec),
             NamedSharding(mesh, col_spec),

@@ -220,6 +220,8 @@ def device_orient(
     """
     import jax
 
+    from repro.runtime.spans import span
+
     edges = np.asarray(edges)
     m = int(len(edges))
     if m == 0:
@@ -232,13 +234,15 @@ def device_orient(
             f"device build needs 1 <= n <= {_DEVICE_MAX} and m <= "
             f"{_DEVICE_MAX} (int32 device indices), got n={n} m={m}"
         )
+    with span("tc.orient.digest"):
+        h = hashlib.blake2b(digest_size=16)
+        h.update(repr((n, m, bool(reorder), "orient-v1")).encode())
+        h.update(np.ascontiguousarray(edges).tobytes())
     bucket = _pow2_ceil(m)
-    padded = np.full((bucket, 2), n, dtype=np.int32)
-    padded[:m] = edges
-    h = hashlib.blake2b(digest_size=16)
-    h.update(repr((n, m, bool(reorder), "orient-v1")).encode())
-    h.update(np.ascontiguousarray(edges).tobytes())
-    ed, m_dev = jax.device_put((padded, np.int32(m)))
+    with span("tc.orient.upload"):
+        padded = np.full((bucket, 2), n, dtype=np.int32)
+        padded[:m] = edges
+        ed, m_dev = jax.device_put((padded, np.int32(m)))
     src, dst, indptr = _orient_step()(ed, m_dev, n, bool(reorder))
     return DeviceGraph(
         src=src,

@@ -62,7 +62,6 @@ __all__ = [
     "gather_total_reference",
     "gather_segment_totals_pallas",
     "gather_segment_totals_reference",
-    "modeled_hbm_bytes",
 ]
 
 # Lane width of a store tile: the DMA unit is one (W, 128) tile.
@@ -316,22 +315,3 @@ def gather_total_reference(
     cols = jnp.take(col_data, jnp.maximum(col_idx, 0), axis=0)
     pc = swar_popcount_u32(rows & cols).sum(axis=1)
     return jnp.where(mask, pc, 0).sum(dtype=jnp.int32)
-
-
-def modeled_hbm_bytes(num_pairs: int, words_per_slice: int, *, fused: bool) -> int:
-    """Analytic HBM traffic of the execute stage for ``num_pairs`` work items.
-
-    fused:    the Pallas kernel: indices in, one whole ``(W, 128)`` store
-              tile DMA'd to VMEM per operand of every pair (128x the words
-              the pair uses), scalar out.
-    unfused:  XLA gather reads the store words *and writes* ``[P, W]``
-              operand buffers, then the reduction kernel reads them back —
-              3x the gathered-word traffic plus the same index traffic.
-    """
-    word_bytes = 4
-    gathered = 2 * num_pairs * words_per_slice * word_bytes  # row + col sides
-    index = 2 * num_pairs * 4
-    out = 4
-    if fused:
-        return LANES * gathered + index + out
-    return 3 * gathered + index + out

@@ -20,7 +20,6 @@ from repro.kernels.tc_gather_popcount import (
     gather_segment_totals_reference,
     gather_total_pallas,
     gather_total_reference,
-    modeled_hbm_bytes,
 )
 
 
@@ -274,15 +273,3 @@ def test_distributed_stripe_split_matches_exact(small_graph, monkeypatch):
     monkeypatch.setattr(dtc, "INT32_SAFE_WORDS", 512 * sbf.words_per_slice)
     assert wl.num_pairs > 512 * 4
     assert dtc.distributed_tc_count(sbf, wl, mesh) == want
-
-
-def test_modeled_hbm_bytes_fused_advantage():
-    """The fused kernel moves one whole (W, 128) tile per operand, so its
-    modeled traffic is 128x the gathered words; unfused moves the words 3x.
-    The fused path's advantage is no [P, W] buffer, not fewer bytes."""
-    fused = modeled_hbm_bytes(1000, 2, fused=True)
-    unfused = modeled_hbm_bytes(1000, 2, fused=False)
-    gathered = 2 * 1000 * 2 * 4
-    index_and_out = 2 * 1000 * 4 + 4
-    assert fused == 128 * gathered + index_and_out
-    assert unfused == 3 * gathered + index_and_out

@@ -14,11 +14,12 @@ to the NumPy reference:
     flags + a cumsum replace ``np.unique``/``searchsorted``, and a
     scatter-add of one-hot bit words replaces ``np.bitwise_or.at`` (each
     edge contributes a distinct bit, so add == OR exactly).
-  * **Schedule** — ``_worklist_step``: the row-slice expansion becomes a
-    ``searchsorted`` over the per-edge candidate prefix sums, the column
-    membership test a fixed-iteration branchless binary search (identical
-    lower-bound semantics to ``sbf._window_searchsorted``), and the hit
-    compaction a cumsum scatter. Pairs come back compacted in the same
+  * **Schedule** — ``_worklist_step``: the row-slice expansion maps each
+    candidate lane to its edge with one scatter of edge ids at their
+    candidate offsets and a running max (a segment id, no search), the
+    column membership test a fixed-iteration branchless binary search
+    (identical lower-bound semantics to ``sbf._window_searchsorted``), and
+    the hit compaction a cumsum scatter. Pairs come back compacted in the same
     order as the host build, padded to a pow2 bucket with the executor's
     ``-1`` no-op sentinel.
 
@@ -159,8 +160,10 @@ def _get_jits() -> dict:
         The three phases run under the named scopes ``tc_expand``,
         ``tc_search`` and ``tc_compact``, which every operation's metadata
         carries into the profiler's trace. ``cb`` is the static candidate
-        bucket. The binary search runs a fixed iteration count (enough to
-        fully converge any window within the column store), replicating
+        bucket. Expansion finds each lane's edge without a loop: edge ids
+        scattered at their candidate offsets, then ``cummax``. The binary
+        search runs a fixed iteration count (enough to fully converge any
+        window within the column store), replicating
         ``_window_searchsorted``'s lower-bound loop branchlessly.
         """
         bucket = src.shape[0]
@@ -174,10 +177,15 @@ def _get_jits() -> dict:
             start = cum - cnt
             total = cum[-1]
             lane = jnp.arange(cb, dtype=jnp.int32)
-            e = jnp.minimum(
-                jnp.searchsorted(cum, lane, side="right").astype(jnp.int32),
-                bucket - 1,
+            # Lane l belongs to the last edge whose start is <= l: scatter
+            # each edge id at its start (a zero-candidate edge shares its
+            # start with the next edge, which max keeps; starts at cb drop)
+            # and carry it forward with a running max.
+            seed = jnp.zeros(cb, jnp.int32).at[start].max(
+                jnp.arange(bucket, dtype=jnp.int32),
+                indices_are_sorted=True, mode="drop",
             )
+            e = jax.lax.cummax(seed, axis=0)
             lane_valid = lane < total
             row_pos = row_ptr[u[e]] + (lane - start[e])
             ks = row_idx[jnp.clip(row_pos, 0, row_idx.shape[0] - 1)]
@@ -511,8 +519,8 @@ def device_delta_worklist(
     """Delta worklist: valid slice pairs for an arbitrary touched-edge subset.
 
     The streaming analogue of ``device_build_worklist``, reusing the same
-    jitted ``worklist_step`` (searchsorted expansion, branchless binary
-    search, cumsum compaction) over *just* the touched edges of a delta
+    jitted ``worklist_step`` (scatter + running-max expansion, branchless
+    binary search, cumsum compaction) over *just* the touched edges of a delta
     batch instead of the whole graph — pair positions come back in the
     SBF's global record coordinates, bit-identical to the host
     ``sbf.build_worklist_pairs`` on the same subset (parity-tested). Edges

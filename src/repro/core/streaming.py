@@ -167,7 +167,7 @@ class StreamingTCState:
     ``backend`` picks the executor mode (``STREAM_BACKENDS``); ``build``
     picks the delta-worklist front end — ``'host'`` (NumPy
     ``build_worklist_pairs``), ``'device'`` (``core.build
-    .device_delta_worklist``: the jitted searchsorted/compaction step over
+    .device_delta_worklist``: the jitted expansion/search/compaction step over
     just the touched edges, bit-identical), or ``'auto'`` (device on
     accelerator backends). A 2-axis ``mesh`` streams against a resident
     ``Sharded2DExecutor`` (host build only — the planner needs host

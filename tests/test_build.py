@@ -24,10 +24,11 @@ from repro.core import (
     device_build_sbf,
     device_build_trace_counts,
     device_build_worklist,
+    device_delta_worklist,
     tcim_count,
     tcim_count_graph,
 )
-from repro.core.sbf import Worklist, _window_searchsorted
+from repro.core.sbf import Worklist, _window_searchsorted, build_worklist_pairs
 from repro.data.graph_pipeline import load_graph
 from repro.graphs import build_graph, device_orient, rmat
 from repro.graphs.exact import triangles_intersection
@@ -135,6 +136,73 @@ def test_device_count_matches_exact_and_host():
 )
 def test_device_build_tiny_graphs(edges, n, want):
     assert tcim_count(edges, n=n, build="device").triangles == want
+
+
+def _lane_graph():
+    """A 64-vertex graph whose vertices 0-7, 20-21 and 40-41 are isolated, so
+    an edge out of them has no candidate lanes (32-bit slices: 0-2 per edge)."""
+    rng = np.random.default_rng(14)
+    live = np.r_[8:20, 22:40, 42:64]
+    pairs = np.sort(rng.choice(live, size=(400, 2)), axis=1)
+    g = build_graph(np.unique(pairs[pairs[:, 0] < pairs[:, 1]], axis=0), n=64)
+    return g, build_sbf(g, 32)
+
+
+def _lane_case(name, g, sb):
+    """Oriented edge subsets that put zero-candidate edges, padding and the
+    candidate total where the lane->edge map can go wrong."""
+    cnt = sb.row_ptr[g.edges[:, 0] + 1] - sb.row_ptr[g.edges[:, 0]]
+    some = g.edges[cnt > 0]
+    sink = np.array([[0, 30], [1, 31], [2, 50], [20, 44], [21, 45], [40, 60],
+                     [41, 61]])
+    if name == "leading_zero":
+        return np.r_[sink[:3], some[:13]]
+    if name == "zero_runs_in_middle":
+        lo, mid, hi = (some[(some[:, 0] > a) & (some[:, 0] < b)]
+                       for a, b in ((0, 20), (21, 40), (41, 64)))
+        return np.r_[lo[:5], sink[3:5], mid[:4], sink[5:], hi[:6]]
+    if name == "trailing_padded":
+        return some[:13]
+    if name == "pow2_total":
+        ones = g.edges[cnt == 1]
+        return np.r_[ones[:16], [[41, 63]]]
+    return some[10:11]  # single_edge
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["leading_zero", "zero_runs_in_middle", "trailing_padded", "pow2_total",
+     "single_edge"],
+)
+def test_lane_to_edge_map_edge_cases(case):
+    """Both device worklist entry points equal the host pairs on subsets whose
+    candidate lanes start late, skip runs of edges, end before the padding,
+    fill their pow2 bucket exactly (starts at the bucket drop) or come from
+    a single edge."""
+    g, sb_h = _lane_graph()
+    sb_d = device_build_graph(g, 32).sbf
+    sub = np.asarray(_lane_case(case, g, sb_h), dtype=np.int64)
+    dg = device_orient(sub, n=g.n, reorder=False)
+    src, dst = (dg.to_host().edges[:, i] for i in (0, 1))
+    assert np.array_equal(np.sort(sub, axis=0), np.sort(np.c_[src, dst], axis=0))
+    cnt = sb_h.row_ptr[src + 1] - sb_h.row_ptr[src]
+    m, total = len(src), int(cnt.sum())
+    assert {
+        "leading_zero": cnt[0] == 0 and cnt[-1] > 0,
+        "zero_runs_in_middle": cnt[0] > 0 and cnt[-1] > 0
+        and np.count_nonzero(np.diff((cnt == 0).astype(int)) == 1) == 2,
+        "trailing_padded": m & (m - 1) != 0 and (cnt > 0).all(),
+        "pow2_total": total & (total - 1) == 0 and m & (m - 1) != 0,
+        "single_edge": m == 1,
+    }[case]
+    want = build_worklist_pairs(src, dst, sb_h)
+    assert len(want[0]) > 0
+    for got in (device_build_worklist(dg, sb_d).to_host(),
+                device_delta_worklist(src, dst, sb_d).to_host()):
+        assert got.num_pairs == len(want[0])
+        assert np.array_equal(got.pair_edge, want[0])
+        assert np.array_equal(got.pair_row_pos, want[1])
+        assert np.array_equal(got.pair_col_pos, want[2])
 
 
 def test_one_transfer_before_execute():

@@ -148,6 +148,18 @@ def test_worklist_step_ops_fall_under_exactly_one_scope():
     assert {s for p in paths for s in SCOPES if s in p} == set(SCOPES)
 
 
+def test_worklist_expansion_compiles_to_no_loop():
+    """The lane->edge map is one scatter and a running max: the compiled
+    worklist step holds a single loop, the search's, and no op of
+    ``tc_expand`` is a loop (a log-depth gather loop cost ~13 s per
+    com-Youtube count on a TPU v5e)."""
+    step = _get_jits()["worklist"]
+    hlo = step.lower(*_worklist_args()).compile().as_text()
+    loops = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in hlo.splitlines() if re.search(r"\swhile\(", line)]
+    assert len(loops) == 1 and "tc_search" in loops[0], loops
+
+
 def _module_name(lowered) -> str:
     return re.search(r"HloModule (\S+?),", lowered.as_text(dialect="hlo")).group(1)
 

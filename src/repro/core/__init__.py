@@ -2,6 +2,7 @@
 
 Public API:
     tcim_count / tcim_count_graph   end-to-end bitwise triangle counting
+    tcim_vertex_counts              per-vertex counts and local clustering
     build_sbf / build_worklist      sparsity-aware compression + scheduling
     plan_execution / ExecutionPlan  placement + owner-grouped work stripes
     Executor / ExecutorPool         device-resident fused execute stage
@@ -74,8 +75,10 @@ from repro.core.tcim import (
     BUILDS,
     TCFuture,
     TCResult,
+    TCVertexResult,
     tcim_count,
     tcim_count_graph,
+    tcim_vertex_counts,
 )
 from repro.core.cachesim import CacheStats, simulate_lru
 from repro.core.energymodel import (
@@ -145,6 +148,8 @@ __all__ = [
     "TCResult",
     "tcim_count",
     "tcim_count_graph",
+    "TCVertexResult",
+    "tcim_vertex_counts",
     "CacheStats",
     "simulate_lru",
     "MramConstants",

@@ -86,12 +86,16 @@ import numpy as np
 from repro.core import sbf as sbf_mod
 from repro.core.plan import clamp_chunk_pairs, plan_fusion, pow2_ceil as _pow2_ceil
 from repro.kernels import ops, ref
-from repro.kernels.common import on_cpu
+from repro.kernels.common import on_cpu, swar_popcount_u32
+from repro.kernels.tc_gather_popcount import gather_and_words_reference
 from repro.runtime.contracts import max_transfers, no_host_sync
 
 __all__ = [
     "CountFuture",
     "MultiCountFuture",
+    "VertexCountFuture",
+    "VERTEX_EXECUTE_IMPL",
+    "VERTEX_IMPL",
     "Executor",
     "ExecutorPool",
     "MultiGraphExecutor",
@@ -102,6 +106,12 @@ __all__ = [
 ]
 
 EXECUTOR_MODES = ("fused", "gather_then_kernel", "pallas_items", "jnp")
+
+# What runs a per-vertex count (``Executor.vertex_counts_async``): each
+# pair's AND words come from the jnp gather mirror, and the attribution to
+# vertices is XLA scatter-adds. The one place the vertex path names them.
+VERTEX_EXECUTE_IMPL = "jnp_mirror"
+VERTEX_IMPL = "xla_scatter"
 
 _INT32_MAX = 2**31 - 1
 
@@ -213,6 +223,18 @@ def _pad_rows_pow2(a: np.ndarray) -> np.ndarray:
     return np.concatenate(
         [a, np.zeros((bucket - rows,) + a.shape[1:], dtype=a.dtype)]
     )
+
+
+def _resident_pow2(a, *, pad: bool = True):
+    """An int32 index array on the device: device arrays as they are, host
+    arrays uploaded once, padded with zeros to their pow2 bucket (so traces
+    are keyed by buckets) unless ``pad`` is off."""
+    if isinstance(a, jax.Array):
+        return a
+    a = np.asarray(a, dtype=np.int32)
+    if pad:
+        a = np.concatenate([a, np.zeros(_pow2_ceil(max(len(a), 1)) - len(a), np.int32)])
+    return jax.device_put(a)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
@@ -348,6 +370,129 @@ def _chunk_step_fn(
     return jax.jit(tc_chunk_step, donate_argnums=argnums)
 
 
+def _select_bit(words, rank):
+    """Position of the ``rank``-th set bit (from 0, least significant
+    first) of each row of ``words`` ([L, W] uint32 read as one W*32-bit
+    string), for ranks below the row's popcount. Word by word, then a
+    5-step halving search inside the word: elementwise, no gather."""
+    pos = jnp.zeros(rank.shape, jnp.int32)
+    found = jnp.zeros(rank.shape, bool)
+    for j in range(words.shape[1]):
+        x = words[:, j]
+        pc = swar_popcount_u32(x)
+        here = ~found & (rank < pc)
+        r = rank
+        bit = jnp.zeros(rank.shape, jnp.int32)
+        for width in (16, 8, 4, 2, 1):
+            low = swar_popcount_u32(x & jnp.uint32((1 << width) - 1))
+            up = r >= low
+            r = jnp.where(up, r - low, r)
+            x = jnp.where(up, x >> jnp.uint32(width), x)
+            bit = jnp.where(up, bit + width, bit)
+        pos = jnp.where(here, j * 32 + bit, pos)
+        rank = jnp.where(found | here, rank, rank - pc)
+        found = found | here
+    return pos
+
+
+@functools.lru_cache(maxsize=None)
+def _vertex_step_fn(slice_bits: int, donate: bool):
+    """Module-level jitted per-vertex attribution of one pair chunk.
+
+    For the pair (edge (u, v), slice k), bit b of the AND word
+    ``row[u] & col[v]`` is set exactly when w = k * slice_bits + b closes
+    the triangle u < w < v (Eq. 5 before its BitCount is summed). The step
+    adds the pair's popcount to ``counts[u]`` and ``counts[v]``, and one to
+    ``counts[w]`` for every set bit. Set bits are spread one per lane: a
+    lane finds its pair with a scatter of pair ids at their popcount
+    offsets and a running max (as the device build's expansion does), and
+    its bit with ``_select_bit``. A chunk whose set bits outnumber its
+    lanes runs more windows of lanes; the loop stops at the chunk's total.
+    Returns (counts, [chunk total, pairs with a non-zero word]), every
+    count int32: one chunk's total is bounded by ``clamp_chunk_pairs``.
+    ``counts`` (donated off the CPU) is indexed by the relabelled ids,
+    padded to a pow2 bucket; negative indices are no-op pairs.
+    """
+
+    def tc_vertex_step(row_data, col_data, ridx, cidx, eidx, src, dst,
+                       slice_idx, counts):
+        words = gather_and_words_reference(row_data, col_data, ridx, cidx)
+        pc = swar_popcount_u32(words).sum(axis=1)
+        size = counts.shape[0]
+        live = pc > 0
+        e = jnp.maximum(eidx, 0)
+        counts = (
+            counts.at[jnp.where(live, src[e], size)].add(pc, mode="drop")
+            .at[jnp.where(live, dst[e], size)].add(pc, mode="drop")
+        )
+        k = slice_idx[jnp.maximum(ridx, 0)]
+        lanes = pc.shape[0]
+        cum = jnp.cumsum(pc)
+        start = cum - pc
+        total = cum[-1]
+        pair = jnp.arange(lanes, dtype=jnp.int32)
+
+        def window(j, counts):
+            base = j * lanes
+            rel = start - base
+            # The last pair starting at or before ``base`` owns lane 0;
+            # zero-popcount pairs share their start with the next pair,
+            # which the max keeps.
+            seed = jnp.zeros(lanes, jnp.int32).at[
+                jnp.where(rel >= 0, rel, lanes)
+            ].max(pair, mode="drop")
+            seed = seed.at[0].max(jnp.sum(start <= base) - 1)
+            p = jax.lax.cummax(seed, axis=0)
+            lane = base + pair
+            bit = _select_bit(words[p], lane - start[p])
+            w = jnp.where(lane < total, k[p] * slice_bits + bit, size)
+            return counts.at[w].add(1, mode="drop")
+
+        counts = jax.lax.fori_loop(0, (total + lanes - 1) // lanes, window,
+                                   counts)
+        return counts, jnp.stack([total, jnp.sum(live.astype(jnp.int32))])
+
+    return jax.jit(tc_vertex_step, donate_argnums=(8,) if donate else ())
+
+
+def tc_vertex_close(counts, new_id, stats):
+    """Counts in the caller's vertex ids (``counts[new_id]``) and the
+    chunks' stacked [total, non-zero pairs] scalars, for one readback."""
+    return counts[new_id], jnp.stack(stats)
+
+
+_vertex_close = jax.jit(tc_vertex_close)
+
+
+class VertexCountFuture:
+    """A dispatched per-vertex count whose readback is deferred.
+
+    ``result(new_id)`` maps the counts back to the caller's ids on the
+    device, reads the [n] counts and the per-chunk scalars back in one
+    transfer, and returns (total triangles, int64 [n] counts, pairs with a
+    non-zero AND word). Raises ``OverflowError`` when 3 x the total passes
+    int32, the bound of the device's int32 counts.
+    """
+
+    def __init__(self, counts, stats: list):
+        self._counts = counts
+        self._stats = stats
+
+    def result(self, new_id) -> tuple[int, np.ndarray, int]:
+        if not self._stats:
+            return 0, np.zeros(new_id.shape[0], np.int64), 0
+        # tclint: sync-ok(the one readback of a per-vertex count, at future close)
+        counts, stats = jax.device_get(_vertex_close(
+            self._counts, _resident_pow2(new_id, pad=False), self._stats))
+        total = sum(int(t) for t in stats[:, 0])  # exact: host ints
+        if 3 * total > _INT32_MAX:
+            raise OverflowError(
+                f"{total} triangles: per-vertex counts past int32 "
+                "(3 x the total bounds them) are not exact on the device"
+            )
+        return total, counts.astype(np.int64), sum(int(z) for z in stats[:, 1])
+
+
 class Executor:
     """Device-resident execute stage for one pair of SBF slice stores.
 
@@ -456,21 +601,23 @@ class Executor:
         except Exception:
             return -1
 
-    def _chunks(self, row_idx: np.ndarray, col_idx: np.ndarray):
-        """Yield host-side (ridx, cidx) int32 chunks in pow2 buckets."""
-        p = len(row_idx)
+    def _chunks(self, *index_arrays: np.ndarray):
+        """Yield host-side int32 chunks of the index arrays (row, column,
+        ...) in pow2 buckets."""
+        p = len(index_arrays[0])
         c = self.chunk_pairs
         for start in range(0, p, c):
-            r = np.asarray(row_idx[start : start + c], dtype=np.int32)
-            cc = np.asarray(col_idx[start : start + c], dtype=np.int32)
-            bucket = _pow2_ceil(len(r))
-            if bucket != len(r):  # ragged tail -> pad to its pow2 bucket
-                pad = bucket - len(r)
-                r = np.concatenate([r, np.full(pad, -1, np.int32)])
-                cc = np.concatenate([cc, np.full(pad, -1, np.int32)])
-            yield r, cc
+            size = min(c, p - start)
+            bucket = _pow2_ceil(size)
+            chunk = []
+            for a in index_arrays:
+                a = np.asarray(a[start : start + c], dtype=np.int32)
+                if bucket != size:  # ragged tail -> pad to its pow2 bucket
+                    a = np.concatenate([a, np.full(bucket - size, -1, np.int32)])
+                chunk.append(a)
+            yield tuple(chunk)
 
-    def _device_chunks(self, row_idx: np.ndarray, col_idx: np.ndarray):
+    def _device_chunks(self, *index_arrays: np.ndarray):
         """Upload chunks to the device, one ahead of the consumer.
 
         With double buffering, chunk i+1's pad/convert work and its
@@ -480,26 +627,27 @@ class Executor:
         either way.
         """
         return staged_uploads(
-            self._chunks(row_idx, col_idx),
-            lambda rc: (jax.device_put(rc[0]), jax.device_put(rc[1])),
+            self._chunks(*index_arrays),
+            lambda chunk: tuple(jax.device_put(a) for a in chunk),
             double_buffer=self.double_buffer,
         )
 
-    def _resident_chunks(self, row_idx, col_idx):
+    def _resident_chunks(self, *index_arrays):
         """Pow2 chunk windows of device-resident index arrays (no staging —
         the indices are already on device; windows are jitted static slices)."""
-        p = int(row_idx.shape[0])
+        p = int(index_arrays[0].shape[0])
         c = self.chunk_pairs
-        if p <= c and p == _pow2_ceil(p) and row_idx.dtype == jnp.int32:
+        if p <= c and p == _pow2_ceil(p) and all(
+            a.dtype == jnp.int32 for a in index_arrays
+        ):
             # The common device-worklist shape (one pow2 bucket): no copy.
-            yield row_idx, col_idx
+            yield index_arrays
             return
         for start in range(0, p, c):
             size = min(c, p - start)
             bucket = _pow2_ceil(size)
-            yield (
-                _resident_window(row_idx, start, size, bucket),
-                _resident_window(col_idx, start, size, bucket),
+            yield tuple(
+                _resident_window(a, start, size, bucket) for a in index_arrays
             )
 
     def _accumulate(self, device_chunks, step, worst_pairs: int) -> CountFuture:
@@ -570,6 +718,36 @@ class Executor:
     def count(self, wl) -> int:
         """Triangle contribution of a work list (Eq. 5 execute+reduce)."""
         return self.count_async(wl).result()
+
+    @no_host_sync()
+    def vertex_counts_async(self, wl, src, dst, slice_idx, n: int) -> VertexCountFuture:
+        """Dispatch the per-vertex triangle count T of a work list; defer
+        the readback to the future's ``result(new_id)``.
+
+        ``src``/``dst`` are the oriented edges the work list's
+        ``pair_edge`` indexes, and ``slice_idx`` the row store's slice
+        numbers (``SlicedBitmap.row_slice_idx``): device arrays of a
+        device build, or host arrays, uploaded here pow2-padded. The pair
+        chunks are ``count``'s, and each runs ``_vertex_step_fn``; the
+        counts of the ``n`` vertices stay on the device in int32 across
+        chunks, padded to a pow2 bucket.
+        """
+        counts = jnp.zeros(_pow2_ceil(max(n, 1)), jnp.int32)
+        stats: list = []
+        if not wl.num_pairs:
+            return VertexCountFuture(counts, stats)
+        src, dst, slice_idx = (_resident_pow2(a) for a in (src, dst, slice_idx))
+        index_arrays = (wl.pair_row_pos, wl.pair_col_pos, wl.pair_edge)
+        if isinstance(index_arrays[0], jax.Array):
+            chunks = self._resident_chunks(*index_arrays)
+        else:
+            chunks = self._device_chunks(*index_arrays)
+        step = _vertex_step_fn(self.slice_bits, not on_cpu())
+        for ridx, cidx, eidx in chunks:
+            counts, chunk_stats = step(self.row_data, self.col_data, ridx, cidx,
+                                       eidx, src, dst, slice_idx, counts)
+            stats.append(chunk_stats)
+        return VertexCountFuture(counts, stats)
 
     def update_stores(self, row_lanes, col_lanes) -> None:
         """Scatter word-level edits (``sbf.UpdateLanes``) into the resident

@@ -6,7 +6,8 @@ accelerators (HPEC'18 GPU/FPGA) also do truss decomposition. These build
 directly on Eq. 5's per-pair popcounts:
 
   edge_support       per-edge triangle counts (segment-sum of pair counts)
-  clustering         per-vertex local clustering coefficient + transitivity
+  clustering         per-vertex local clustering coefficient + transitivity,
+                     on the device (``tcim_vertex_counts``)
   ktruss             k-truss decomposition by iterative support peeling
 """
 from __future__ import annotations
@@ -14,6 +15,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.sbf import build_sbf, build_worklist
+from repro.core.tcim import tcim_vertex_counts
 from repro.graphs.csr import Graph, build_graph
 from repro.kernels import ops
 
@@ -68,20 +70,17 @@ def _triangle_list(g: Graph) -> np.ndarray:
 
 
 def clustering_coefficients(g: Graph) -> tuple[np.ndarray, float]:
-    """(per-vertex local clustering coefficient, global transitivity)."""
-    tris = _triangle_list(g)
-    tri_per_vertex = np.zeros(g.n, dtype=np.int64)
-    for col in range(3):
-        np.add.at(tri_per_vertex, tris[:, col], 1)
-    deg = np.zeros(g.n, dtype=np.int64)
-    np.add.at(deg, g.edges[:, 0], 1)
-    np.add.at(deg, g.edges[:, 1], 1)
-    wedges = deg * (deg - 1) // 2
-    with np.errstate(divide="ignore", invalid="ignore"):
-        local = np.where(wedges > 0, tri_per_vertex / np.maximum(wedges, 1), 0.0)
-    total_wedges = int(wedges.sum())
-    transitivity = 3.0 * len(tris) / total_wedges if total_wedges else 0.0
-    return local, transitivity
+    """(per-vertex local clustering coefficient, global transitivity).
+
+    Runs on the device path (``core.tcim.tcim_vertex_counts``): T(v) from
+    the per-pair AND words, LCC(v) = T(v) / (d(v)(d(v)-1)/2), and the
+    transitivity 3 x triangles / wedges.
+    """
+    res = tcim_vertex_counts(g.edges, n=g.n)
+    deg = np.bincount(g.edges.reshape(-1), minlength=g.n).astype(np.int64)
+    total_wedges = int((deg * (deg - 1) // 2).sum())
+    transitivity = 3.0 * res.triangles / total_wedges if total_wedges else 0.0
+    return res.lcc, transitivity
 
 
 def _edge_id_map(g: Graph):

@@ -35,6 +35,13 @@ stage under the same keys without the ``tc.`` prefix; for a stage that
 only dispatches device work that is the dispatch, and the stage's device
 time is in the trace.
 
+``tcim_vertex_counts`` runs the same build, pool and pair chunks for the
+per-vertex answer: T(v), the triangles through each vertex, and the local
+clustering coefficient (LDBC Graphalytics LCC). Its pair chunks run
+``Executor.vertex_counts_async`` in place of the count's execute, under
+``tc.vertex``; ``tc.vertex.materialize`` is the inverse relabel and the
+one readback of T, and ``tc.vertex.lcc`` the host's float64 LCC.
+
 Backends for the execute stage (mapped onto Executor modes):
     'pallas_total'   fused gather–AND–popcount executor (default; the TCIM
                      device — indices travel, slice stores stay put)
@@ -57,22 +64,29 @@ import numpy as np
 from repro.core import build as build_mod
 from repro.core import sbf as sbf_mod
 from repro.core.bitmat import bitpack_matrix
-from repro.core.executor import CountFuture, ExecutorPool
+from repro.core.executor import (
+    VERTEX_EXECUTE_IMPL,
+    VERTEX_IMPL,
+    CountFuture,
+    ExecutorPool,
+)
 from repro.core.plan import SCHEDULES, DeviceTopology, plan_execution
 from repro.core.streaming import (  # noqa: F401  (re-exported: streaming API)
     DeltaResult,
     StreamingTCState,
     tcim_count_delta,
 )
-from repro.graphs.csr import Graph, build_graph
+from repro.graphs.csr import Graph, build_graph, degree_relabel
 from repro.kernels import ops
 from repro.runtime.spans import next_count_id, span
 
 __all__ = [
     "TCResult",
     "TCFuture",
+    "TCVertexResult",
     "tcim_count",
     "tcim_count_graph",
+    "tcim_vertex_counts",
     "tcim_count_delta",
     "StreamingTCState",
     "DeltaResult",
@@ -123,6 +137,14 @@ class TCResult:
     def __repr__(self) -> str:  # compact, log-friendly
         t = ", ".join(f"{k}={v:.4f}" for k, v in self.timings_s.items())
         return f"TCResult(triangles={self.triangles}, backend={self.backend}, {t})"
+
+
+@dataclasses.dataclass(repr=False)
+class TCVertexResult(TCResult):
+    """A per-vertex count (``tcim_vertex_counts``), in the caller's ids."""
+
+    vertex_triangles: np.ndarray  # int64 [n]: T(v), triangles through v
+    lcc: np.ndarray  # float64 [n]: T(v) / (d(v)(d(v)-1)/2), 0 if d(v) < 2
 
 
 class TCFuture:
@@ -621,3 +643,68 @@ def tcim_count(
     )
     res.timings_s = {**orient, **res.timings_s}
     return res
+
+
+@_counted
+def tcim_vertex_counts(
+    edges: np.ndarray,
+    *,
+    n: int | None = None,
+    chunk_pairs: int = 1 << 20,
+    pool: ExecutorPool | None = None,
+    build: str = "auto",
+) -> TCVertexResult:
+    """Per-vertex triangle counts and local clustering of a canonical
+    undirected edge list, on the device.
+
+    Builds as ``tcim_count`` does (degree relabel, 64-bit SBF, worklist;
+    ``build`` as there, a ``DeviceCapacityError`` under ``'auto'`` answered
+    by the host build), takes the replicated executor of ``pool`` (the
+    module's by default) with ``chunk_pairs``-pair chunks, and runs each
+    pair chunk through ``Executor.vertex_counts_async``: the pair's AND
+    words and their attribution to its three vertices stay on the device,
+    in int32, read back once. Returns a ``TCVertexResult``: the exact
+    total, ``vertex_triangles`` (int64 [n]) and ``lcc`` (float64 [n],
+    computed on the host: the TPU has no float64), both in the caller's
+    vertex ids. ``stats`` adds ``vertex_impl``, ``vertex_pairs`` (pairs
+    attributed) and ``vertex_nonzero_pairs`` (pairs with a non-zero AND
+    word). Raises ``OverflowError`` past 3 x total > int32.
+    """
+    edges = np.asarray(edges)
+    if n is None:
+        n = int(edges.max()) + 1 if len(edges) else 0
+    timings: dict[str, float] = {}
+    db = None
+    if _resolve_build(build, "pallas_total", None, len(edges)) == "device":
+        db = _try_device_build(
+            lambda: build_mod.device_build(edges, n=n), build,
+        )
+    if db is not None:
+        timings.update(db.timings_s)
+        g, sb, wl = db.graph, db.sbf, db.worklist
+        src, dst, new_id = g.src, g.dst, g.new_id
+    else:
+        with span("tc.orient", timings, "orient"):
+            g = build_graph(edges, n=n, reorder=True)
+            new_id = degree_relabel(edges, n)
+        with span("tc.compress", timings, "compress"):
+            sb = sbf_mod.build_sbf(g, 64)
+        with span("tc.schedule", timings, "schedule"):
+            wl = sbf_mod.build_worklist(g, sb)
+        src, dst = g.edges[:, 0], g.edges[:, 1]
+    stats = sbf_mod.sbf_stats(g, sb, wl)
+    stats.update(placement="replicated", build="device" if db else "host",
+                 execute_impl=VERTEX_EXECUTE_IMPL, vertex_impl=VERTEX_IMPL)
+    with span("tc.vertex", timings, "vertex"):
+        ex = _pooled_executor(sb, "pallas_total", chunk_pairs, pool)
+        fut = ex.vertex_counts_async(wl, src, dst, sb.row_slice_idx, n)
+        # The host's share, while the device attributes.
+        deg = np.bincount(edges.reshape(-1), minlength=n).astype(np.int64)
+    with span("tc.vertex.materialize", timings, "vertex.materialize"):
+        total, counts, nonzero = fut.result(new_id)
+    with span("tc.vertex.lcc", timings, "vertex.lcc"):
+        wedges = deg * (deg - 1) // 2
+        lcc = np.zeros(n, dtype=np.float64)
+        np.divide(counts, wedges, out=lcc, where=wedges > 0)
+    stats.update(vertex_pairs=wl.num_pairs, vertex_nonzero_pairs=nonzero)
+    return TCVertexResult(total, "vertex", stats, timings, counts, lcc)

@@ -29,6 +29,7 @@ __all__ = [
     "DeviceGraph",
     "build_graph",
     "degree_order",
+    "degree_relabel",
     "device_orient",
     "device_graph_trace_counts",
     "upper_triangular_edges",
@@ -84,19 +85,24 @@ def upper_triangular_edges(edges: np.ndarray) -> np.ndarray:
     return edges[order]
 
 
+def degree_relabel(edges: np.ndarray, n: int) -> np.ndarray:
+    """``new_id[v]``: vertex v's id in non-decreasing (undirected) degree
+    order, ties by id. ``x[new_id]`` maps a per-vertex array of the
+    relabelled graph back to the original ids."""
+    deg = np.bincount(np.asarray(edges).reshape(-1), minlength=n)
+    # Stable argsort => deterministic relabelling.
+    perm = np.argsort(deg, kind="stable")  # old ids in degree order
+    new_id = np.empty(n, dtype=np.int64)
+    new_id[perm] = np.arange(n, dtype=np.int64)
+    return new_id
+
+
 def degree_order(edges: np.ndarray, n: int) -> np.ndarray:
     """Relabel vertices by non-decreasing (undirected) degree.
 
     Returns the relabelled canonical edge list (src < dst under new ids).
     """
-    deg = np.zeros(n, dtype=np.int64)
-    np.add.at(deg, edges[:, 0], 1)
-    np.add.at(deg, edges[:, 1], 1)
-    # Stable argsort => deterministic relabelling.
-    perm = np.argsort(deg, kind="stable")  # old ids in degree order
-    new_id = np.empty(n, dtype=np.int64)
-    new_id[perm] = np.arange(n, dtype=np.int64)
-    e = new_id[edges]
+    e = degree_relabel(edges, n)[edges]
     lo = np.minimum(e[:, 0], e[:, 1])
     hi = np.maximum(e[:, 0], e[:, 1])
     out = np.stack([lo, hi], axis=1)
@@ -116,6 +122,9 @@ class DeviceGraph:
     stages never need an implicit host->device scalar transfer; ``m`` is the
     same value on the host. ``content_key`` digests the *input* edge list, so
     executor pools can key device-built stores without reading them back.
+    ``new_id`` is the degree relabel (``degree_relabel`` on device, int32
+    [n]; ``None`` without one): per-vertex results of the relabelled graph
+    map back to the caller's ids as ``x[new_id]``.
     """
 
     src: object  # jax int32 [bucket]
@@ -126,6 +135,7 @@ class DeviceGraph:
     m: int
     bucket: int
     content_key: str
+    new_id: object = None  # jax int32 [n], or None when not relabelled
 
     def to_host(self) -> Graph:
         """Materialize the oriented CSR back on the host (sync)."""
@@ -161,6 +171,7 @@ def _orient_step():
             and the (src, dst) lexsort is two stable passes (dst then src).
             Sentinel lanes carry vertex id ``n`` (> every real id), so they
             sort to the tail and every downstream stage masks by ``m``.
+            The relabel ``new_id`` comes back too (``None`` without one).
             """
             bucket = edges.shape[0]
             valid = jnp.arange(bucket, dtype=jnp.int32) < m
@@ -179,6 +190,8 @@ def _orient_step():
                 s = jnp.where(valid, new_id[jnp.clip(src, 0, n - 1)], n)
                 d = jnp.where(valid, new_id[jnp.clip(dst, 0, n - 1)], n)
                 src, dst = jnp.minimum(s, d), jnp.maximum(s, d)
+            else:
+                new_id = None
             o1 = jnp.argsort(dst, stable=True)
             s1, d1 = src[o1], dst[o1]
             o2 = jnp.argsort(s1, stable=True)
@@ -189,7 +202,7 @@ def _orient_step():
             indptr = jnp.concatenate(
                 [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)]
             )
-            return src_s, dst_s, indptr
+            return src_s, dst_s, indptr, new_id
 
         fn = _DEVICE_JITS["orient"] = orient
     return fn
@@ -243,7 +256,7 @@ def device_orient(
         padded = np.full((bucket, 2), n, dtype=np.int32)
         padded[:m] = edges
         ed, m_dev = jax.device_put((padded, np.int32(m)))
-    src, dst, indptr = _orient_step()(ed, m_dev, n, bool(reorder))
+    src, dst, indptr, new_id = _orient_step()(ed, m_dev, n, bool(reorder))
     return DeviceGraph(
         src=src,
         dst=dst,
@@ -253,6 +266,7 @@ def device_orient(
         m=m,
         bucket=bucket,
         content_key=h.hexdigest(),
+        new_id=new_id,
     )
 
 

@@ -35,6 +35,8 @@ This module is the device analogue of the MRAM computational array: the
     (``python -m benchmarks.kernel_micro --chip``), so the batch size is
     fixed: the cost is the per-pair scalar loop, not the DMA depth.
   * ``gather_total_pallas`` — the same kernel with one segment.
+  * ``gather_and_words_reference`` — the per-pair AND words themselves,
+    before any popcount is summed (the per-vertex attribution's input).
   * ``gather_total_reference`` / ``gather_segment_totals_reference`` —
     vectorized jnp mirrors with identical semantics (including the
     negative-index contract). On the CPU backend the interpreted kernel is a
@@ -62,6 +64,7 @@ __all__ = [
     "gather_total_reference",
     "gather_segment_totals_pallas",
     "gather_segment_totals_reference",
+    "gather_and_words_reference",
 ]
 
 # Lane width of a store tile: the DMA unit is one (W, 128) tile.
@@ -288,11 +291,8 @@ def gather_segment_totals_reference(
     g = p // bucket
     if g == 0:
         return jnp.zeros((0,), jnp.int32)
-    mask = (row_idx >= 0) & (col_idx >= 0)
-    rows = jnp.take(row_data, jnp.maximum(row_idx, 0), axis=0)
-    cols = jnp.take(col_data, jnp.maximum(col_idx, 0), axis=0)
-    pc = swar_popcount_u32(rows & cols).sum(axis=1)
-    per_pair = jnp.where(mask, pc, 0)
+    words = gather_and_words_reference(row_data, col_data, row_idx, col_idx)
+    per_pair = swar_popcount_u32(words).sum(axis=1)
     return per_pair.reshape(g, bucket).sum(axis=1, dtype=jnp.int32)
 
 
@@ -310,8 +310,26 @@ def gather_total_reference(
     """
     if row_idx.shape[0] == 0:
         return jnp.int32(0)
+    words = gather_and_words_reference(row_data, col_data, row_idx, col_idx)
+    return swar_popcount_u32(words).sum(dtype=jnp.int32)
+
+
+def gather_and_words_reference(
+    row_data: jax.Array,
+    col_data: jax.Array,
+    row_idx: jax.Array,
+    col_idx: jax.Array,
+) -> jax.Array:
+    """Per-pair AND words ``row_data[row_idx] & col_data[col_idx]`` ->
+    ``[P, W]`` uint32, all-zero for a pair with a negative index.
+
+    Eq. 5 before its BitCount is summed: bit ``b`` of pair (edge (u, v),
+    slice k) is set exactly when ``w = k * slice_bits + b`` closes the
+    triangle u < w < v. The totals above are its popcount sums; the
+    per-vertex attribution (``core.executor``) reads the words themselves.
+    """
     mask = (row_idx >= 0) & (col_idx >= 0)
     rows = jnp.take(row_data, jnp.maximum(row_idx, 0), axis=0)
     cols = jnp.take(col_data, jnp.maximum(col_idx, 0), axis=0)
-    pc = swar_popcount_u32(rows & cols).sum(axis=1)
-    return jnp.where(mask, pc, 0).sum(dtype=jnp.int32)
+    # Zeroing one side of the AND suffices: x & 0 == 0.
+    return jnp.where(mask[:, None], rows, 0) & cols

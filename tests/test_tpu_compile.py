@@ -18,7 +18,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import build as build_mod
-from repro.core.executor import _chunk_step_fn, _fused_step_fn
+from repro.core.executor import (
+    _chunk_step_fn,
+    _fused_step_fn,
+    _vertex_close,
+    _vertex_step_fn,
+)
 from repro.kernels.tc_gather_popcount import (
     gather_segment_totals_pallas,
     gather_total_pallas,
@@ -29,6 +34,8 @@ EDGE_BUCKET = 1 << 22  # pow2 bucket of |E| = 2,987,624
 STORE_ROWS = 1 << 22  # pow2 bucket of ~2.6M valid slices per side
 CAND_BUCKET = 1 << 26  # pow2 bucket of 44,510,150 candidates
 CHUNK = 1 << 20  # Executor / ServeConfig default chunk_pairs
+PAIR_BUCKET = 1 << 24  # pow2 bucket of 14,953,478 slice pairs
+VERTEX_BUCKET = 1 << 21  # pow2 bucket of |V|: the per-vertex counts
 W = 2  # words per slice at slice_bits=64
 
 
@@ -130,3 +137,23 @@ def test_device_build_worklist_step_compiles(shape):
         edges, edges, shape((), jnp.int32), ptr, idx, ptr, idx, CAND_BUCKET
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+@pytest.mark.parametrize("pairs", [CHUNK, PAIR_BUCKET])
+def test_vertex_step_compiles(shape, pairs):
+    """The per-vertex attribution step, at the executor's chunk and at the
+    whole pair bucket in one chunk."""
+    step = _vertex_step_fn(64, True)
+    store = shape((STORE_ROWS, W), jnp.uint32)
+    idx = shape((pairs,), jnp.int32)
+    edges = shape((EDGE_BUCKET,), jnp.int32)
+    compiled = step.lower(
+        store, store, idx, idx, idx, edges, edges,
+        shape((STORE_ROWS,), jnp.int32), shape((VERTEX_BUCKET,), jnp.int32),
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+    close = _vertex_close.lower(
+        shape((VERTEX_BUCKET,), jnp.int32), shape((YOUTUBE_N,), jnp.int32),
+        [shape((2,), jnp.int32)] * (PAIR_BUCKET // CHUNK),
+    ).compile()
+    assert close.memory_analysis() is not None

@@ -17,11 +17,12 @@ to the NumPy reference:
   * **Schedule** — ``_worklist_step``: the row-slice expansion maps each
     candidate lane to its edge with one scatter of edge ids at their
     candidate offsets and a running max (a segment id, no search), the
-    column membership test a fixed-iteration branchless binary search
-    (identical lower-bound semantics to ``sbf._window_searchsorted``), and
-    the hit compaction a cumsum scatter. Pairs come back compacted in the same
-    order as the host build, padded to a pow2 bucket with the executor's
-    ``-1`` no-op sentinel.
+    column membership test a branchless binary search (identical
+    lower-bound semantics to ``sbf._window_searchsorted``) run for the
+    bit length of the longest column window, a bound computed on the
+    device, and the hit compaction a cumsum scatter. Pairs come back
+    compacted in the same order as the host build, padded to a pow2 bucket
+    with the executor's ``-1`` no-op sentinel.
 
 Shape bucketing mirrors the executor's store buckets: edges pad to
 ``pow2_ceil(m)``, slice stores to ``pow2_ceil(nvs)``, candidate/pair arrays
@@ -162,9 +163,12 @@ def _get_jits() -> dict:
         carries into the profiler's trace. ``cb`` is the static candidate
         bucket. Expansion finds each lane's edge without a loop: edge ids
         scattered at their candidate offsets, then ``cummax``. The binary
-        search runs a fixed iteration count (enough to fully converge any
-        window within the column store), replicating
-        ``_window_searchsorted``'s lower-bound loop branchlessly.
+        search replicates ``_window_searchsorted``'s lower-bound loop
+        branchlessly. A step leaves at most floor(s/2) of an active window
+        of s, so the bit length of the longest column window converges
+        every lane; that count is traced, not static, so graphs whose
+        longest windows differ share one compiled step. Returns the padded
+        pairs and the stacked scalars (pair count, search steps).
         """
         bucket = src.shape[0]
         n = row_ptr.shape[0] - 1
@@ -203,9 +207,9 @@ def _get_jits() -> dict:
         with jax.named_scope("tc_search"):
             v = jnp.clip(dst[e], 0, n - 1)
             lo, hi = col_ptr[v], col_ptr[v + 1]
-            pos, _ = jax.lax.fori_loop(
-                0, int(col_cap).bit_length() + 1, body, (lo, hi)
-            )
+            longest = jnp.max(col_ptr[1:] - col_ptr[:-1], initial=0)
+            steps = 32 - jax.lax.clz(longest)
+            pos, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
             hit = lane_valid & (pos < hi) & (
                 col_idx[jnp.minimum(pos, col_cap - 1)] == ks
             )
@@ -215,7 +219,7 @@ def _get_jits() -> dict:
             pe = jnp.full(cb, -1, jnp.int32).at[tgt].set(e, mode="drop")
             pr = jnp.full(cb, -1, jnp.int32).at[tgt].set(row_pos, mode="drop")
             pc = jnp.full(cb, -1, jnp.int32).at[tgt].set(pos, mode="drop")
-            return pe, pr, pc, jnp.sum(hit.astype(jnp.int32))
+            return pe, pr, pc, jnp.stack([jnp.sum(hit.astype(jnp.int32)), steps])
 
     @functools.partial(jax.jit, static_argnums=(1,))
     def prefix(a, k):
@@ -248,8 +252,10 @@ class DeviceWorklist:
 
     The executor consumes the padded arrays directly (its fused step treats
     negative indices as exact no-ops), so the pairs never bounce through the
-    host. ``num_pairs`` is the real (non-sentinel) pair count — already
-    synced during bucket sizing, so reading it is free.
+    host. ``num_pairs`` is the real (non-sentinel) pair count and
+    ``search_steps`` the binary search's trip count (the longest column
+    window's bit length) — both synced in the one readback that sizes the
+    pair bucket, so reading them is free.
     """
 
     pair_edge: object  # jax int32 [PB]
@@ -259,6 +265,7 @@ class DeviceWorklist:
     num_candidates: int
     m_edges: int
     n_slices: int
+    search_steps: int
 
     def compute_reduction(self) -> float:
         naive = self.m_edges * self.n_slices
@@ -341,12 +348,13 @@ def _make_worklist(
             "indexing; build this graph on the host (build='host')"
         )
     cb = pow2_ceil(max(cand_total, 1))
-    pe, pr, pc, npair = jits["worklist"](
+    pe, pr, pc, scalars = jits["worklist"](
         dg.src, dg.dst, dg.m_dev,
         sb.row_ptr, sb.row_slice_idx, sb.col_ptr, sb.col_slice_idx, cb,
     )
     with span("tc.schedule.pair_wait"):
-        num_pairs = int(npair)  # scalar readback sizes the pair bucket
+        # one scalar-sized readback sizes the pair bucket
+        num_pairs, search_steps = (int(x) for x in np.asarray(scalars))
     pb = pow2_ceil(max(num_pairs, 1))
     return DeviceWorklist(
         pair_edge=jits["prefix"](pe, pb),
@@ -356,6 +364,7 @@ def _make_worklist(
         num_candidates=cand_total,
         m_edges=dg.m,
         n_slices=sb.n_slices,
+        search_steps=search_steps,
     )
 
 
@@ -548,10 +557,10 @@ def device_delta_worklist(
             "device indexing; split the batch or build on the host"
         )
     cb = pow2_ceil(max(int(cand), 1))
-    pe, pr, pc, npair = jits["worklist"](
+    pe, pr, pc, scalars = jits["worklist"](
         src_d, dst_d, m, row_ptr, row_idx, col_ptr, col_idx, cb
     )
-    num_pairs = int(npair)
+    num_pairs, search_steps = (int(x) for x in np.asarray(scalars))
     pb = pow2_ceil(max(num_pairs, 1))
     return DeviceWorklist(
         pair_edge=jits["prefix"](pe, pb),
@@ -561,4 +570,5 @@ def device_delta_worklist(
         num_candidates=int(cand),
         m_edges=m,
         n_slices=sb.n_slices,
+        search_steps=search_steps,
     )

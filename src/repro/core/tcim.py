@@ -431,6 +431,7 @@ def _finish_device(
         )
         stats["placement"] = "replicated"
         stats["build"] = "device"
+        stats["search_steps"] = db.worklist.search_steps
         with span("tc.execute", timings, "execute"):
             ex = _pooled_executor(db.sbf, backend, chunk_pairs, pool)
             stats["execute_impl"] = ex.execute_impl
@@ -667,8 +668,9 @@ def tcim_vertex_counts(
     total, ``vertex_triangles`` (int64 [n]) and ``lcc`` (float64 [n],
     computed on the host: the TPU has no float64), both in the caller's
     vertex ids. ``stats`` adds ``vertex_impl``, ``vertex_pairs`` (pairs
-    attributed) and ``vertex_nonzero_pairs`` (pairs with a non-zero AND
-    word). Raises ``OverflowError`` past 3 x total > int32.
+    attributed), ``vertex_nonzero_pairs`` (pairs with a non-zero AND
+    word) and, for a device build, ``search_steps``. Raises
+    ``OverflowError`` past 3 x total > int32.
     """
     edges = np.asarray(edges)
     if n is None:
@@ -695,6 +697,8 @@ def tcim_vertex_counts(
     stats = sbf_mod.sbf_stats(g, sb, wl)
     stats.update(placement="replicated", build="device" if db else "host",
                  execute_impl=VERTEX_EXECUTE_IMPL, vertex_impl=VERTEX_IMPL)
+    if db is not None:
+        stats["search_steps"] = wl.search_steps
     with span("tc.vertex", timings, "vertex"):
         ex = _pooled_executor(sb, "pallas_total", chunk_pairs, pool)
         fut = ex.vertex_counts_async(wl, src, dst, sb.row_slice_idx, n)

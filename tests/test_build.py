@@ -28,6 +28,7 @@ from repro.core import (
     tcim_count,
     tcim_count_graph,
 )
+from repro.core.plan import pow2_ceil
 from repro.core.sbf import Worklist, _window_searchsorted, build_worklist_pairs
 from repro.data.graph_pipeline import load_graph
 from repro.graphs import build_graph, device_orient, rmat
@@ -205,6 +206,39 @@ def test_lane_to_edge_map_edge_cases(case):
         assert np.array_equal(got.pair_col_pos, want[2])
 
 
+def _hub_edges(spokes, n):
+    """Triangles (u, u + 1, n - 1) for each spoke u: the top vertex's column
+    window holds one record per distinct 32-bit slice among the spokes, every
+    other window one."""
+    h = n - 1
+    return np.asarray(
+        [[[u, u + 1], [u, h], [u + 1, h]] for u in spokes], dtype=np.int64
+    ).reshape(-1, 2)
+
+
+@pytest.mark.parametrize("longest", [0, 1, 7, 8, 9, 15, 16, 17, 20],
+                         ids=lambda w: f"window{w}")
+def test_search_steps_at_window_bit_lengths(longest):
+    """The binary search runs the longest column window's bit length and
+    stays exact at its off-by-one points: an empty column side, one slice,
+    2^k - 1, 2^k, 2^k + 1, and a window of every slice (n_slices = 20)."""
+    n = 640
+    g = build_graph(_hub_edges(range(0, 32 * longest, 32), n), n=n)
+    sb_h = build_sbf(g, 32)
+    assert sb_h.n_slices == 20
+    assert int(np.diff(sb_h.col_ptr).max(initial=0)) == longest
+    wl_h = build_worklist(g, sb_h)
+    got = [device_delta_worklist(g.edges[:, 0], g.edges[:, 1], sb_h)]
+    if longest:  # the full build needs an edge
+        got.append(_assert_build_matches(g, 32).worklist)
+    for dwl in got:
+        assert dwl.search_steps == longest.bit_length()
+        wl = dwl.to_host()
+        assert dwl.num_pairs == wl_h.num_pairs
+        for name in ("pair_edge", "pair_row_pos", "pair_col_pos"):
+            assert np.array_equal(getattr(wl, name), getattr(wl_h, name)), name
+
+
 def test_one_transfer_before_execute():
     """The device build performs exactly ONE host->device transfer (the
     padded edge list) and no implicit transfers anywhere before the execute
@@ -267,6 +301,36 @@ def test_same_bucket_rebuild_adds_zero_traces():
     # Rebuilding the SAME graph is always a pure cache hit.
     device_build(edges_a, n=400)
     assert device_build_trace_counts() == after
+
+
+def test_longest_window_change_adds_zero_traces():
+    """Two same-bucket graphs whose longest column windows differ in bit
+    length (7 -> 3 search steps, 9 -> 4) share one compiled worklist step:
+    the trip count is traced, not static."""
+    n = 512
+    rng = np.random.default_rng(0)
+    pairs = np.sort(rng.choice(np.arange(416, n - 1), size=(120, 2)), axis=1)
+    filler = pairs[pairs[:, 0] < pairs[:, 1]]
+    graphs = [
+        build_graph(np.unique(np.r_[_hub_edges(spokes, n), filler], axis=0), n=n)
+        for spokes in ([0, 32, 64, 96, 128, 160, 192, 2, 34], range(0, 288, 32))
+    ]
+    db_a = device_build_graph(graphs[0], 32)
+    before = device_build_trace_counts()
+    if -1 in before.values():
+        pytest.skip("private jit cache-size API unavailable on this jax")
+    db_b = device_build_graph(graphs[1], 32)
+    assert (db_a.worklist.search_steps, db_b.worklist.search_steps) == (3, 4)
+    assert db_a.sbf.row_slice_data.shape == db_b.sbf.row_slice_data.shape
+    assert db_a.sbf.col_slice_data.shape == db_b.sbf.col_slice_data.shape
+    assert db_a.worklist.pair_row_pos.shape == db_b.worklist.pair_row_pos.shape
+    assert (pow2_ceil(db_a.worklist.num_candidates)
+            == pow2_ceil(db_b.worklist.num_candidates))
+    assert device_build_trace_counts() == before
+    for g, db in zip(graphs, (db_a, db_b)):
+        want = build_worklist(g, build_sbf(g, 32))
+        assert np.array_equal(db.worklist.to_host().pair_col_pos,
+                              want.pair_col_pos)
 
 
 def test_device_build_async_overlaps():

@@ -189,7 +189,10 @@ def test_device_delta_worklist_matches_host():
     src = g.edges[idx, 0].astype(np.int64)
     dst = g.edges[idx, 1].astype(np.int64)
     host = build_worklist_pairs(src, dst, sb)
-    dev = device_delta_worklist(src, dst, sb).to_host()
+    dwl = device_delta_worklist(src, dst, sb)
+    longest = int(np.diff(sb.col_ptr).max(initial=0))
+    assert dwl.search_steps == longest.bit_length() > 0
+    dev = dwl.to_host()
     assert np.array_equal(dev.pair_edge, host[0])
     assert np.array_equal(dev.pair_row_pos, host[1])
     assert np.array_equal(dev.pair_col_pos, host[2])

@@ -22,7 +22,6 @@ def main() -> None:
         fig5_hit_miss,
         fig6_energy,
         kernel_micro,
-        lm_roofline,
         table3_slice_size,
         table4_valid_pct,
         table5_runtime,
@@ -35,7 +34,6 @@ def main() -> None:
         "fig5": fig5_hit_miss.run,
         "fig6": fig6_energy.run,
         "kernels": kernel_micro.run,
-        "lm_roofline": lm_roofline.run,
     }
     selected = args.only.split(",") if args.only else list(suites)
     print("name,us_per_call,derived")

@@ -1,4 +1,3 @@
-from repro.data.tokens import SyntheticLMDataset
 from repro.data.graph_pipeline import load_graph
 
-__all__ = ["SyntheticLMDataset", "load_graph"]
+__all__ = ["load_graph"]

@@ -1,4 +1,4 @@
-"""Distribution layer: sharded TC, LM shardings, gradient compression."""
+"""Distribution layer: sharded triangle counts and resilient, resumable counts."""
 from repro.distributed.tc import (
     Sharded2DExecutor,
     ShardedColsExecutor,

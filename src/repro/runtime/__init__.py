@@ -4,7 +4,7 @@ from repro.runtime.fault import (
     SimulatedFailure,
     StragglerMonitor,
 )
-from repro.runtime.elastic import RemeshPlan, elastic_remesh_plan, tc_remesh_plan
+from repro.runtime.elastic import RemeshPlan, tc_remesh_plan
 from repro.runtime.contracts import (
     ContractViolation,
     contracts_enabled,
@@ -19,7 +19,6 @@ __all__ = [
     "SimulatedFailure",
     "StragglerMonitor",
     "RemeshPlan",
-    "elastic_remesh_plan",
     "tc_remesh_plan",
     "ContractViolation",
     "contracts_enabled",

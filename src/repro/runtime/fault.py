@@ -5,9 +5,9 @@ the design here is checkpoint/restart (the only strategy that composes with
 XLA SPMD's gang-scheduled execution) plus:
 
   * ``FailureInjector`` — deterministic chaos-monkey for tests/examples:
-    raises SimulatedFailure at configured steps; the driver's restart path
-    (examples/fault_tolerant_train.py, tests/test_runtime.py) proves
-    bit-exact resume from the last committed checkpoint.
+    raises SimulatedFailure at configured steps; the resilient count's
+    restart path (examples/fault_tolerant_tc.py, tests/test_resilient.py)
+    proves an exact resume from the last committed checkpoint.
   * ``StragglerMonitor`` — EWMA step-time tracker. On real pods, persistent
     stragglers (failing HBM, thermal throttling) show up as a stable
     multiplicative slowdown of the whole gang; the monitor flags them and

@@ -10,7 +10,6 @@ from repro.core.bitmat import bitpack_matrix, bitunpack_matrix
 from repro.core.sbf import build_sbf, build_worklist
 from repro.graphs import build_graph
 from repro.graphs.exact import triangles_bruteforce, triangles_dense_trace
-from repro.runtime.elastic import elastic_remesh_plan
 
 
 @st.composite
@@ -84,32 +83,3 @@ def test_bitpack_roundtrip_property(n, c):
     rng = np.random.default_rng(n * 1000 + c)
     dense = (rng.random((n, c)) < 0.5).astype(np.uint8)
     assert (bitunpack_matrix(bitpack_matrix(dense), c) == dense).all()
-
-
-@settings(max_examples=50, deadline=None)
-@given(
-    st.integers(min_value=1, max_value=1024),
-    st.integers(min_value=1, max_value=4096),
-)
-def test_elastic_plan_always_valid(devices, batch):
-    plan = elastic_remesh_plan((2, 16, 16), ("pod", "data", "model"), devices, batch)
-    if plan.ok:
-        assert plan.new_device_count <= max(devices, 1)
-        assert plan.new_shape[2] == 16  # model axis preserved
-        dp = plan.new_shape[0] * plan.new_shape[1]
-        assert dp == 1 or batch % dp == 0
-
-
-@settings(max_examples=30, deadline=None)
-@given(st.lists(st.floats(min_value=-100, max_value=100), min_size=1, max_size=64))
-def test_int8_error_feedback_bounded(values):
-    """Error-feedback residual stays bounded by one quantization step."""
-    import jax.numpy as jnp
-
-    from repro.distributed.compression import dequantize_int8, ef_update
-
-    g = jnp.asarray(np.array(values, dtype=np.float32))
-    residual = jnp.zeros_like(g)
-    for _ in range(5):
-        q, scale, residual = ef_update(g, residual)
-        assert float(jnp.abs(residual).max()) <= float(scale) * 0.5 + 1e-6

@@ -25,6 +25,8 @@ from repro.graphs import build_graph, rmat
 from repro.graphs.exact import triangles_intersection
 from repro.launch.tc_serve import ServeConfig, TCServer
 
+from graph_cases import GRAPH_CASES, GRAPH_IDS, num_vertices
+
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
@@ -207,6 +209,34 @@ def test_server_fuse_off_still_exact(mixed_jobs):
     results = {r.request_id: r for r in srv.serve(jobs)}
     assert all(r.placement == "replicated" for r in results.values())
     assert [results[i].count for i in range(len(jobs))] == want
+
+
+def _case_job(edges):
+    g = build_graph(edges, n=num_vertices(edges))
+    sbf = build_sbf(g, 64)
+    return (sbf, build_worklist(g, sbf)), triangles_intersection(g)
+
+
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
+def test_server_mixed_wave_on_every_graph_shape(name, edges, mixed_jobs):
+    """Each graph shape served inside one wave with the mixed jobs: its
+    count and every other request's are exact."""
+    jobs, want = mixed_jobs
+    job, count = _case_job(edges)
+    srv = TCServer(ServeConfig())
+    results = {r.request_id: r for r in srv.serve([job] + jobs)}
+    assert srv.stats["waves"] == 1
+    assert [results[i].status for i in range(len(jobs) + 1)] == ["ok"] * (len(jobs) + 1)
+    assert [results[i].count for i in range(len(jobs) + 1)] == [count] + want, name
+
+
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
+def test_server_solo_unfused_on_every_graph_shape(name, edges):
+    job, count = _case_job(edges)
+    srv = TCServer(ServeConfig(fuse=False))
+    (res,) = srv.serve([job])
+    assert res.status == "ok" and res.count == count, name
+    assert res.placement == "replicated"
 
 
 def test_server_sharded_solo_placement():

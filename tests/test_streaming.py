@@ -8,6 +8,7 @@ the delta path promises: steady-state batches add zero retraces, removals
 keep records resident (zero rows), growth adopts new store buckets, and
 malformed batches are rejected before any state mutates.
 """
+import itertools
 import os
 import subprocess
 import sys
@@ -32,6 +33,8 @@ from repro.core.executor import scatter_update_trace_count
 from repro.data.graph_pipeline import load_graph
 from repro.graphs import build_graph, rmat
 from repro.graphs.exact import triangles_intersection
+
+from graph_cases import GRAPH_CASES, GRAPH_IDS, num_vertices
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
@@ -86,6 +89,42 @@ def test_streaming_matches_oracle_after_every_batch(name, slice_bits):
                 absent.discard(tuple(e))
     # And against the public end-to-end API on the final edge set.
     assert state.verify() == state.triangles
+
+
+def _closing_batch(edges, n):
+    """Absent edges that close triangles: up to eight wedges of the graph
+    closed in place, a new vertex ``n`` joined to both ends of the first
+    three edges, and a triangle on the new vertices ``n .. n + 2``."""
+    present = {(int(u), int(v)) for u, v in edges}
+    nbrs: dict[int, list[int]] = {}
+    for u, v in present:
+        nbrs.setdefault(u, []).append(v)
+        nbrs.setdefault(v, []).append(u)
+    batch = set()
+    for v in sorted(nbrs):
+        for wedge in itertools.combinations(sorted(nbrs[v]), 2):
+            if len(batch) < 8 and wedge not in present:
+                batch.add(wedge)
+    for u, v in edges[:3]:
+        batch |= {(int(u), n), (int(v), n)}
+    batch |= {(n, n + 1), (n, n + 2), (n + 1, n + 2)}
+    return np.array(sorted(batch), dtype=np.int64)
+
+
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
+@pytest.mark.parametrize("build", ["host", "device"])
+def test_streaming_closes_and_reopens_triangles_on_every_graph_shape(name, edges, build):
+    """Seeded from each graph shape, a batch that closes triangles and then
+    its removal each leave the running count equal to a recount."""
+    n = num_vertices(edges)
+    batch = _closing_batch(edges, n)
+    state = StreamingTCState(edges, n=n + 3, build=build)
+    base = _oracle(edges, n + 3)
+    assert state.triangles == base
+    grown = _oracle(np.concatenate([edges, batch]), n + 3)
+    assert grown > base
+    assert state.apply_batch(added=batch).triangles == grown == state.triangles
+    assert state.apply_batch(removed=batch).triangles == base == state.triangles
 
 
 def test_tcim_count_delta_wrapper():

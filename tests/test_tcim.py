@@ -4,41 +4,35 @@ import pytest
 
 from repro.core import BACKENDS, build_sbf, build_worklist, simulate_lru, tcim_count
 from repro.core.sbf import sbf_stats
-from repro.graphs import (
-    build_graph,
-    complete_graph,
-    erdos_renyi,
-    grid_road,
-    rmat,
-    triangle_free_bipartite,
-)
-from repro.graphs.exact import (
-    triangles_bruteforce,
-    triangles_dense_trace,
-    triangles_intersection,
-)
+from repro.core.tcim import TCFuture
+from repro.graphs import build_graph, erdos_renyi, rmat
+from repro.graphs.exact import triangles_dense_trace, triangles_intersection
 
-GRAPH_CASES = [
-    ("rmat", rmat(400, 2500, seed=1)),
-    ("er", erdos_renyi(300, 1500, seed=2)),
-    ("k16", complete_graph(16)),
-    ("bipartite", triangle_free_bipartite(200, 800, seed=3)),
-    ("road", grid_road(400, seed=4)),
-    ("empty", np.zeros((0, 2), dtype=np.int64)),
-    ("single_edge", np.array([[0, 1]], dtype=np.int64)),
-    ("triangle", np.array([[0, 1], [0, 2], [1, 2]], dtype=np.int64)),
-]
+from graph_cases import GRAPH_CASES, GRAPH_IDS, num_vertices
 
 
-@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=[c[0] for c in GRAPH_CASES])
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
 @pytest.mark.parametrize("backend", BACKENDS)
-def test_backend_matches_oracles(name, edges, backend):
-    n = int(edges.max()) + 1 if len(edges) else 4
+@pytest.mark.parametrize("build", ["host", "device"])
+def test_backend_matches_oracles(name, edges, backend, build):
+    n = num_vertices(edges)
     g = build_graph(edges, n=n)
     want = triangles_dense_trace(g)
     assert triangles_intersection(g) == want
-    got = tcim_count(edges, n=n, backend=backend).triangles
-    assert got == want, (name, backend)
+    got = tcim_count(edges, n=n, backend=backend, build=build).triangles
+    assert got == want, (name, backend, build)
+
+
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
+@pytest.mark.parametrize("build", ["host", "device"])
+def test_async_count_matches_oracle(name, edges, build):
+    """``async_=True`` hands back a future whose result is the exact count."""
+    n = num_vertices(edges)
+    fut = tcim_count(edges, n=n, build=build, async_=True)
+    assert isinstance(fut, TCFuture)
+    res = fut.result()
+    assert res.triangles == triangles_intersection(build_graph(edges, n=n))
+    assert res.stats["build"] == (build if len(edges) else "host")
 
 
 @pytest.mark.parametrize("slice_bits", [32, 64, 128, 256])

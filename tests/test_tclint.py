@@ -87,7 +87,7 @@ def test_tcl001_quiet_outside_execute_modules():
         def f(x):
             return int(jnp.sum(x))
         """,
-        path="repro/analysis/roofline.py",
+        path="repro/core/metrics.py",
     )
     assert rules == []
 

@@ -21,6 +21,8 @@ from repro.graphs.exact import (
     vertex_triangles,
 )
 
+from graph_cases import GRAPH_CASES, GRAPH_IDS, num_vertices
+
 
 def _star(leaves: int) -> np.ndarray:
     return np.stack([np.zeros(leaves, np.int64), np.arange(1, leaves + 1)], axis=1)
@@ -56,6 +58,15 @@ def test_vertex_counts_match_the_reference_exactly(name):
     assert res.stats["vertex_impl"] == executor_mod.VERTEX_IMPL
     assert res.stats["execute_impl"] == executor_mod.VERTEX_EXECUTE_IMPL
     assert {"vertex", "vertex.materialize", "vertex.lcc"} <= set(res.timings_s)
+
+
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
+@pytest.mark.parametrize("build", ["host", "device"])
+def test_vertex_counts_on_every_graph_shape(name, edges, build):
+    n = num_vertices(edges)
+    res = tcim_vertex_counts(edges, n=n, build=build)
+    _check_exact(res, edges, n)
+    assert res.stats["build"] == (build if len(edges) else "host")
 
 
 @pytest.mark.parametrize("name", ["rmat-a", "k6", "star"])

@@ -25,10 +25,14 @@ to the NumPy reference:
     with the executor's ``-1`` no-op sentinel.
 
 Shape bucketing mirrors the executor's store buckets: edges pad to
-``pow2_ceil(m)``, slice stores to ``pow2_ceil(nvs)``, candidate/pair arrays
-to their own pow2 buckets — so a second graph in the same buckets adds
-**zero** new traces (``device_build_trace_counts`` exposes the jit caches
-for regression tests).
+``pow2_ceil(m)``, slice stores to ``pow2_ceil(nvs)`` and pairs to
+``pow2_ceil(num_pairs)``. The schedule step's candidate lanes run in
+``plan.lane_bucket(total)``: the pow2 bucket below 2^20 lanes, an
+eighth-octave one above it, where every padding lane costs a full share of
+the step's gathers (com-youtube: 46,137,344 lanes, not 2^26). A second
+graph in the same buckets adds **zero** new traces
+(``device_build_trace_counts`` exposes the jit caches for regression
+tests).
 
 Host involvement between the upload and the execute stage is exactly two
 scalar-sized device->host readbacks (valid-slice counts + candidate total,
@@ -46,7 +50,7 @@ import functools
 import numpy as np
 
 from repro.core import sbf as sbf_mod
-from repro.core.plan import pow2_ceil
+from repro.core.plan import lane_bucket, pow2_ceil
 from repro.runtime.contracts import max_transfers, no_host_sync
 from repro.runtime.spans import span
 from repro.graphs.csr import (
@@ -161,14 +165,16 @@ def _get_jits() -> dict:
         The three phases run under the named scopes ``tc_expand``,
         ``tc_search`` and ``tc_compact``, which every operation's metadata
         carries into the profiler's trace. ``cb`` is the static candidate
-        bucket. Expansion finds each lane's edge without a loop: edge ids
-        scattered at their candidate offsets, then ``cummax``. The binary
-        search replicates ``_window_searchsorted``'s lower-bound loop
-        branchlessly. A step leaves at most floor(s/2) of an active window
-        of s, so the bit length of the longest column window converges
-        every lane; that count is traced, not static, so graphs whose
-        longest windows differ share one compiled step. Returns the padded
-        pairs and the stacked scalars (pair count, search steps).
+        bucket (``lane_bucket``; lanes past the total are masked, so the
+        pairs do not depend on it). Expansion finds each lane's edge
+        without a loop: edge ids scattered at their candidate offsets,
+        then ``cummax``. The binary search replicates
+        ``_window_searchsorted``'s lower-bound loop branchlessly. A step
+        leaves at most floor(s/2) of an active window of s, so the bit
+        length of the longest column window converges every lane; that
+        count is traced, not static, so graphs whose longest windows
+        differ share one compiled step. Returns the padded pairs and the
+        stacked scalars (pair count, search steps).
         """
         bucket = src.shape[0]
         n = row_ptr.shape[0] - 1
@@ -224,7 +230,11 @@ def _get_jits() -> dict:
     @functools.partial(jax.jit, static_argnums=(1,))
     def prefix(a, k):
         """Static prefix slice on device (eager ``a[:k]`` would stage its
-        start index through an implicit host->device transfer)."""
+        start index through an implicit host->device transfer). A ``k``
+        past the end extends a pair array with the ``-1`` no-op sentinel:
+        a pow2 pair bucket can exceed a finer candidate bucket."""
+        if k > a.shape[0]:
+            return jnp.concatenate([a, jnp.full(k - a.shape[0], -1, a.dtype)])
         return jax.lax.slice_in_dim(a, 0, k)
 
     _JITS["sbf"] = sbf_step
@@ -255,7 +265,9 @@ class DeviceWorklist:
     host. ``num_pairs`` is the real (non-sentinel) pair count and
     ``search_steps`` the binary search's trip count (the longest column
     window's bit length) — both synced in the one readback that sizes the
-    pair bucket, so reading them is free.
+    pair bucket, so reading them is free. ``cand_bucket`` is the candidate
+    lanes the step ran (``lane_bucket(num_candidates)``), so its padding
+    share is ``1 - num_candidates / cand_bucket``.
     """
 
     pair_edge: object  # jax int32 [PB]
@@ -263,6 +275,7 @@ class DeviceWorklist:
     pair_col_pos: object  # jax int32 [PB]
     num_pairs: int
     num_candidates: int
+    cand_bucket: int
     m_edges: int
     n_slices: int
     search_steps: int
@@ -340,14 +353,15 @@ def _make_worklist(
     cand_total: int,
     cand_shadow: float,
 ) -> DeviceWorklist:
-    """Dispatch the expansion/search/compaction; trim pairs to their bucket."""
+    """Dispatch the expansion/search/compaction over ``lane_bucket`` lanes
+    of candidates; trim (or sentinel-pad) the pairs to their pow2 bucket."""
     jits = _get_jits()
     if cand_shadow >= _CAND_GUARD:
         raise DeviceCapacityError(
             f"candidate total ~{cand_shadow:.3g} is at or past int32 device "
             "indexing; build this graph on the host (build='host')"
         )
-    cb = pow2_ceil(max(cand_total, 1))
+    cb = lane_bucket(max(cand_total, 1))
     pe, pr, pc, scalars = jits["worklist"](
         dg.src, dg.dst, dg.m_dev,
         sb.row_ptr, sb.row_slice_idx, sb.col_ptr, sb.col_slice_idx, cb,
@@ -362,6 +376,7 @@ def _make_worklist(
         pair_col_pos=jits["prefix"](pc, pb),
         num_pairs=num_pairs,
         num_candidates=cand_total,
+        cand_bucket=cb,
         m_edges=dg.m,
         n_slices=sb.n_slices,
         search_steps=search_steps,
@@ -533,8 +548,9 @@ def device_delta_worklist(
     batch instead of the whole graph — pair positions come back in the
     SBF's global record coordinates, bit-identical to the host
     ``sbf.build_worklist_pairs`` on the same subset (parity-tested). Edges
-    pad to a pow2 bucket and index arrays to pow2 row buckets, so repeated
-    same-bucket delta batches add zero traces.
+    pad to a pow2 bucket, index arrays to pow2 row buckets and candidate
+    lanes to ``lane_bucket`` (pow2 for the usual batch, under 2^20 lanes),
+    so repeated same-bucket delta batches add zero traces.
     """
     import jax
 
@@ -556,7 +572,7 @@ def device_delta_worklist(
             f"delta candidate total ~{cand_shadow:.3g} is at or past int32 "
             "device indexing; split the batch or build on the host"
         )
-    cb = pow2_ceil(max(int(cand), 1))
+    cb = lane_bucket(max(int(cand), 1))
     pe, pr, pc, scalars = jits["worklist"](
         src_d, dst_d, m, row_ptr, row_idx, col_ptr, col_idx, cb
     )
@@ -568,6 +584,7 @@ def device_delta_worklist(
         pair_col_pos=jits["prefix"](pc, pb),
         num_pairs=num_pairs,
         num_candidates=int(cand),
+        cand_bucket=cb,
         m_edges=m,
         n_slices=sb.n_slices,
         search_steps=search_steps,

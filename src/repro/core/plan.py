@@ -72,6 +72,8 @@ __all__ = [
     "remaining_worklist",
     "clamp_chunk_pairs",
     "pow2_ceil",
+    "LANE_BUCKET_FLOOR",
+    "lane_bucket",
     "shard_col_bounds",
     "even_range_bounds",
     "weighted_range_bounds",
@@ -83,8 +85,27 @@ __all__ = [
 
 def pow2_ceil(x: int) -> int:
     """Smallest power of two >= x (1 for x <= 1) — the bucket rounding every
-    layer shares (chunk tails, store rows, sharded step lengths)."""
+    layer shares (chunk tails, store rows, pair buckets, sharded step
+    lengths); the device build's candidate lanes use ``lane_bucket``."""
     return 1 << max(0, (x - 1).bit_length())
+
+
+# Below this many lanes the padding of a pow2 bucket costs about what a
+# fresh compile does, so small graphs and delta batches keep pow2 buckets.
+LANE_BUCKET_FLOOR = 1 << 20
+
+
+def lane_bucket(x: int) -> int:
+    """The device build's candidate-lane bucket: ``pow2_ceil(x)`` below
+    ``LANE_BUCKET_FLOOR``, else ``x`` rounded up to a multiple of
+    ``pow2_ceil(x) / 16``: eight buckets an octave, padding under 1/8 of
+    ``x``. Powers of two map to themselves; com-youtube's 44,510,150
+    candidates to 11 * 2^22 = 46,137,344."""
+    if x < LANE_BUCKET_FLOOR:
+        return pow2_ceil(x)
+    g = 1 << max(0, (x - 1).bit_length() - 4)
+    return -(-x // g) * g
+
 
 # "auto" resolves to one of the concrete placements at planning time.
 PLACEMENTS = ("auto", "replicated", "sharded_cols", "sharded_2d")

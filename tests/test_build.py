@@ -27,12 +27,16 @@ from repro.core import (
     device_delta_worklist,
     tcim_count,
     tcim_count_graph,
+    tcim_vertex_counts,
 )
-from repro.core.plan import pow2_ceil
+from repro.core import plan as plan_mod
+from repro.core.plan import lane_bucket, pow2_ceil
 from repro.core.sbf import Worklist, _window_searchsorted, build_worklist_pairs
 from repro.data.graph_pipeline import load_graph
-from repro.graphs import build_graph, device_orient, rmat
+from repro.graphs import build_graph, complete_graph, device_orient, rmat
 from repro.graphs.exact import triangles_intersection
+
+from graph_cases import GRAPH_CASES, GRAPH_IDS, num_vertices
 
 
 def _assert_build_matches(g, slice_bits):
@@ -518,3 +522,97 @@ def test_build_worklist_empty_side_guard():
         row_slice_data=np.zeros((0, sb.words_per_slice), dtype=np.uint32),
     )
     assert build_worklist(g, hollow_row).num_pairs == 0
+
+
+@pytest.fixture
+def fine_buckets(monkeypatch):
+    """Every candidate total takes the eighth-octave lane bucket, so graphs
+    far below ``LANE_BUCKET_FLOOR`` run non-pow2 worklist steps."""
+    monkeypatch.setattr(plan_mod, "LANE_BUCKET_FLOOR", 1)
+
+
+def _assert_padded_pairs(dwl, want):
+    """A device worklist equals the host pairs, ``-1``-padded to pow2."""
+    pb = pow2_ceil(max(len(want[0]), 1))
+    assert dwl.num_pairs == len(want[0])
+    assert dwl.cand_bucket == lane_bucket(max(dwl.num_candidates, 1))
+    for got, ref in zip((dwl.pair_edge, dwl.pair_row_pos, dwl.pair_col_pos), want):
+        got = np.asarray(got)
+        assert got.shape == (pb,)
+        assert np.array_equal(got[: len(ref)], ref)
+        assert (got[len(ref):] == -1).all()
+
+
+@pytest.mark.parametrize("name,edges", GRAPH_CASES, ids=GRAPH_IDS)
+def test_fine_lane_bucket_pairs_match_host(fine_buckets, name, edges):
+    """Both device worklist entry points, run in the eighth-octave candidate
+    bucket, equal the host pairs on every graph shape, and so does the
+    device-built count."""
+    n = num_vertices(edges)
+    g = build_graph(edges, n=n)
+    sb_h = build_sbf(g, 32)
+    src, dst = g.edges[:, 0], g.edges[:, 1]
+    want = build_worklist_pairs(src, dst, sb_h)
+    got = [device_delta_worklist(src, dst, sb_h)]
+    if len(edges):  # the device orient needs an edge
+        dg = device_orient(g.edges, n=n, reorder=False)
+        got.append(device_build_worklist(dg, device_build_sbf(dg, 32)))
+    for dwl in got:
+        _assert_padded_pairs(dwl, want)
+    res = tcim_count(edges, n=n, slice_bits=32, build="device")
+    assert res.triangles == triangles_intersection(g)
+
+
+def test_pair_bucket_past_fine_lane_bucket(fine_buckets):
+    """K40 in one 64-bit slice hits on all 780 candidates: the step runs
+    832 lanes and its pairs are ``-1``-padded to the 1,024-pair bucket."""
+    g = build_graph(complete_graph(40), n=40)
+    sb_h = build_sbf(g, 64)
+    src, dst = g.edges[:, 0], g.edges[:, 1]
+    want = build_worklist_pairs(src, dst, sb_h)
+    dg = device_orient(g.edges, n=40, reorder=False)
+    dsb = device_build_sbf(dg, 64)
+    for dwl in (device_build_worklist(dg, dsb), device_delta_worklist(src, dst, sb_h)):
+        assert (dwl.num_candidates, dwl.num_pairs, dwl.cand_bucket) == (780, 780, 832)
+        assert pow2_ceil(dwl.num_pairs) > dwl.cand_bucket
+        _assert_padded_pairs(dwl, want)
+        assert Executor(dsb).count(dwl) == 9880
+    assert tcim_count(complete_graph(40), build="device").triangles == 9880
+
+
+def test_fine_lane_bucket_shared_across_totals(fine_buckets):
+    """Two graphs whose candidate totals differ (5,326 and 5,206) but share
+    the eighth-octave bucket 5,632, and every other bucket, reuse every
+    compiled build step."""
+    edges = [rmat(400, 2500, seed=s) for s in (1, 2)]
+    db_a = device_build(edges[0], n=400)
+    before = device_build_trace_counts()
+    if -1 in before.values():
+        pytest.skip("private jit cache-size API unavailable on this jax")
+    db_b = device_build(edges[1], n=400)
+    assert device_build_trace_counts() == before
+    wa, wb = db_a.worklist, db_b.worklist
+    assert (wa.num_candidates, wb.num_candidates) == (5326, 5206)
+    assert wa.cand_bucket == wb.cand_bucket == 5632
+    for e, db in zip(edges, (db_a, db_b)):
+        g = build_graph(e, n=400, reorder=True)
+        want = build_worklist(g, build_sbf(g, 64))
+        assert np.array_equal(db.worklist.to_host().pair_col_pos, want.pair_col_pos)
+
+
+@pytest.mark.parametrize("floor", ["default", "lowered"])
+def test_cand_bucket_counter(monkeypatch, floor):
+    """A device count, an LCC query and a delta worklist report the
+    candidate lanes their worklist step ran: below ``LANE_BUCKET_FLOOR``
+    the pow2 bucket (8,192 for 5,326 candidates), else the eighth-octave
+    one (5,632)."""
+    want = 8192
+    if floor == "lowered":
+        monkeypatch.setattr(plan_mod, "LANE_BUCKET_FLOOR", 1)
+        want = 5632
+    edges = rmat(400, 2500, seed=1)
+    assert tcim_count(edges, n=400, build="device").stats["cand_bucket"] == want
+    assert tcim_vertex_counts(edges, n=400, build="device").stats["cand_bucket"] == want
+    g = build_graph(edges, n=400, reorder=True)
+    dwl = device_delta_worklist(g.edges[:, 0], g.edges[:, 1], build_sbf(g, 64))
+    assert (dwl.num_candidates, dwl.cand_bucket) == (5326, want)

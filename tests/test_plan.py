@@ -22,6 +22,7 @@ from repro.core import (
     range_owners,
     weighted_range_bounds,
 )
+from repro.core.plan import LANE_BUCKET_FLOOR, lane_bucket, pow2_ceil
 from repro.core.sbf import SlicedBitmap
 from repro.graphs import build_graph, rmat
 from repro.graphs.exact import triangles_intersection
@@ -265,6 +266,30 @@ def test_chunk_bucket_pow2_and_int32_safe(small_graph):
         c = plan.chunk_pairs
         assert c & (c - 1) == 0 and c <= req
         assert c * sbf.words_per_slice * 32 <= 2**31 - 1
+
+
+@pytest.mark.parametrize(
+    "x,want",
+    [(1, 1), (3, 4), (1000, 1024), (LANE_BUCKET_FLOOR - 1, LANE_BUCKET_FLOOR),
+     (LANE_BUCKET_FLOOR, LANE_BUCKET_FLOOR),
+     (LANE_BUCKET_FLOOR + 1, LANE_BUCKET_FLOOR + (1 << 17)),
+     (3 << 19, 3 << 19), ((3 << 19) + 1, (3 << 19) + (1 << 17)),
+     ((1 << 21) - 1, 1 << 21), (44_510_150, 46_137_344), (1 << 26, 1 << 26),
+     ((1 << 26) + 1, (1 << 26) + (1 << 23)), (2**31 - 2**16, 2**31)],
+)
+def test_lane_bucket(x, want):
+    """pow2 below the floor; from it up eight buckets an octave, padding
+    under 1/8; covering, monotone, idempotent, powers of two fixed."""
+    b = lane_bucket(x)
+    assert b == want >= x
+    assert lane_bucket(b) == b
+    assert lane_bucket(max(x - 1, 1)) <= b <= lane_bucket(x + 1)
+    if x & (x - 1) == 0:
+        assert b == x
+    if x < LANE_BUCKET_FLOOR:
+        assert b == pow2_ceil(x)
+    else:
+        assert 8 * (b - x) < x
 
 
 def test_clamp_chunk_pairs_overflow_raises():

@@ -33,6 +33,7 @@ YOUTUBE_N = 1_134_890
 EDGE_BUCKET = 1 << 22  # pow2 bucket of |E| = 2,987,624
 STORE_ROWS = 1 << 22  # pow2 bucket of ~2.6M valid slices per side
 CAND_BUCKET = 1 << 26  # pow2 bucket of 44,510,150 candidates
+FINE_CAND_BUCKET = 11 << 22  # lane_bucket of 44,510,150: the step's lanes
 CHUNK = 1 << 20  # Executor / ServeConfig default chunk_pairs
 PAIR_BUCKET = 1 << 24  # pow2 bucket of 14,953,478 slice pairs
 VERTEX_BUCKET = 1 << 21  # pow2 bucket of |V|: the per-vertex counts
@@ -135,6 +136,20 @@ def test_device_build_worklist_step_compiles(shape):
     idx = shape((STORE_ROWS,), jnp.int32)
     compiled = worklist_step.lower(
         edges, edges, shape((), jnp.int32), ptr, idx, ptr, idx, CAND_BUCKET
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
+
+
+def test_device_build_worklist_step_compiles_at_lane_bucket(shape):
+    """The worklist step at the lanes com-youtube runs, 11 x 2^22: the
+    step's arrays need not be a power of two long."""
+    assert build_mod.lane_bucket(44_510_150) == FINE_CAND_BUCKET
+    worklist_step = build_mod._get_jits()["worklist"]
+    edges = shape((EDGE_BUCKET,), jnp.int32)
+    ptr = shape((YOUTUBE_N + 1,), jnp.int32)
+    idx = shape((STORE_ROWS,), jnp.int32)
+    compiled = worklist_step.lower(
+        edges, edges, shape((), jnp.int32), ptr, idx, ptr, idx, FINE_CAND_BUCKET
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 30
 
